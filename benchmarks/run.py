@@ -14,6 +14,7 @@ import sys
 import tempfile
 
 from benchmarks.common import Timer
+from repro import compile_cache
 
 
 def smoke() -> None:
@@ -171,6 +172,7 @@ def main(argv=None) -> None:
                     help="tiny-shape smoke of online/sweep/traffic only "
                          "(benchmark bit-rot check for CI)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     q = args.quick
     if args.smoke:
         smoke()

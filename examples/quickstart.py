@@ -9,12 +9,14 @@ against the fixed frequencies of prior systems (Table I).
 """
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (bin_trace, candidate_periods, dominant_reuse,
                         generate, optimal_runtime, prune_insignificant,
                         reuse_distance_histogram, run_cori, table_i_runtimes)
 
 
 def main():
+    compile_cache.enable()
     # 1. Reuse Collector: one profiling run
     trace = generate("backprop")
     bins = bin_trace(trace)

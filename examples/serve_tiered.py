@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as C
+from repro import compile_cache
 from repro.core import OnlineTuner
 from repro.memtier import (PagedPools, TierConfig, TieringManager,
                            cori_tune_period, replay)
@@ -81,6 +82,7 @@ def main(argv=None):
     ap.add_argument("--online", action="store_true",
                     help="closed-loop tuning inside the decode loop")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = C.reduced("gemma3-12b")
     params, _ = mdl.init(jax.random.PRNGKey(0), cfg)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as C
+from repro import compile_cache
 from repro.core import OnlineTuner, shifting_mix_stream
 from repro.memtier import SharedPagedPools, TierConfig, TieringManager
 from repro.models import model as mdl
@@ -99,6 +100,7 @@ def main(argv=None):
                     help="continuous-batch rows (max in-flight requests)")
     ap.add_argument("--requests", type=int, default=6)
     args = ap.parse_args(argv)
+    compile_cache.enable()
     serve_batched(args)
     serve_traffic(args)
 

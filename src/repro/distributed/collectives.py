@@ -16,28 +16,12 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import compat as _compat  # jax.shard_map on 0.4.x
-
-_compat.install()
-
 __all__ = ["compressed_psum", "compressed_psum_ef"]
-
-# 0.4.x's SPMD partitioner dies on all_gather inside a *partial-manual*
-# shard_map body (Check failed: IsManualSubgroup mismatch in
-# HandleAllGather) -- the exact shape the pod-compressed train step uses
-# (pod manual, data/model auto).  On those versions reduce the int32-
-# widened shards with psum instead: the integer accumulation is
-# bit-identical (the scale is globally agreed beforehand), only the wire
-# format widens from int8 to int32 until the jax pin moves.
-_ALL_GATHER_OK = jax.__version_info__ >= (0, 5)
-
 
 def _int_sum(q, axis_name: str):
     """Sum the int8 shards over ``axis_name`` in int32, exactly."""
-    if _ALL_GATHER_OK:
-        allq = jax.lax.all_gather(q, axis_name)      # int8 on the wire
-        return jnp.sum(allq.astype(jnp.int32), axis=0)
-    return jax.lax.psum(q.astype(jnp.int32), axis_name)
+    allq = jax.lax.all_gather(q, axis_name)      # int8 on the wire
+    return jnp.sum(allq.astype(jnp.int32), axis=0)
 
 
 def _quantize_global(x, axis_name: str):

@@ -5,12 +5,14 @@
   * "interpret" -- Pallas interpret mode (CPU-validatable, same kernel body)
   * "reference" -- pure-jnp oracle (autodiff-friendly)
 
-On this CPU container the default is "interpret" for tests and "reference"
-inside jitted model code.
+``impl=None`` (the default) takes the path from the platform
+(``default_impl``): the compiled kernel on a TPU; on the CPU, "interpret"
+here and "reference" inside the served model code.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,9 +23,22 @@ from repro.kernels import paged_attention as _pa
 from repro.kernels import ref as _ref
 
 
+def default_impl(cpu: str) -> str:
+    """The kernel path for the backend this process computes on: the
+    compiled Pallas kernel on a TPU, ``cpu`` on the CPU.  Any other
+    backend raises -- no kernel path was built for it."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return "pallas"
+    if backend == "cpu":
+        return cpu
+    raise ValueError(f"no kernel path for the {backend!r} backend")
+
+
 @functools.partial(jax.jit, static_argnames=("alpha", "threshold", "impl"))
 def page_hist(ids, hotness, *, alpha: float = 0.5, threshold: float = 1.0,
-              impl: str = "interpret"):
+              impl: Optional[str] = None):
+    impl = impl or default_impl("interpret")
     if impl == "reference":
         return _ref.page_hist_ref(ids, hotness, alpha=alpha,
                                   threshold=threshold)
@@ -35,7 +50,8 @@ def page_hist(ids, hotness, *, alpha: float = 0.5, threshold: float = 1.0,
                    static_argnames=("causal", "window", "bq", "bkv", "impl"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     bq: int = _fa.DEFAULT_BQ, bkv: int = _fa.DEFAULT_BKV,
-                    impl: str = "interpret"):
+                    impl: Optional[str] = None):
+    impl = impl or default_impl("interpret")
     if impl == "reference":
         return _ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     return _fa.flash_attention(q, k, v, causal=causal, window=window, bq=bq,
@@ -47,7 +63,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                                     "impl"))
 def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
                     window: int = 0, softcap: float = 0.0,
-                    return_mass: bool = False, impl: str = "interpret"):
+                    return_mass: bool = False, impl: Optional[str] = None):
     # Ragged multi-request tables pad short rows with -1; those entries are
     # already masked out by `lengths`, so clamp them to a valid physical
     # page before the gather (the Pallas index_map would otherwise DMA out
@@ -56,6 +72,7 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
     # -1) leaked into the table -- callers must ensure_resident first; the
     # clamp cannot distinguish that from padding on traced values.
     page_table = jnp.maximum(page_table, 0)
+    impl = impl or default_impl("interpret")
     if impl == "reference":
         return _ref.paged_attention_ref(q, k_pages, v_pages, page_table,
                                         lengths, window=window,
@@ -77,13 +94,14 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
                    static_argnames=("scale", "return_mass", "impl"))
 def paged_attention_mla(q_abs, q_rope, ckv_pages, krope_pages, page_table,
                         lengths, *, scale: float, return_mass: bool = False,
-                        impl: str = "interpret"):
+                        impl: Optional[str] = None):
     """MLA absorbed-matrix decode over compressed paged rows (ckv shared
     across heads + roped krope).  Same ragged-table clamp contract as
     ``paged_attention``; ``scale`` = 1/sqrt(qk_nope_dim + qk_rope_dim).
     Returns the compressed-space context [B, H, R] (callers up-project
     with W_uv) and, with ``return_mass``, the per-page mass f32[B, n]."""
     page_table = jnp.maximum(page_table, 0)
+    impl = impl or default_impl("interpret")
     if impl == "reference":
         return _ref.paged_attention_mla_ref(q_abs, q_rope, ckv_pages,
                                             krope_pages, page_table, lengths,

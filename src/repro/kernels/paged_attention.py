@@ -115,7 +115,7 @@ def _kernel(page_table, lengths, q_ref, k_ref, v_ref, o_ref, mass_ref,
     def _flush():
         l_safe = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
-        mass_ref[0] = jnp.sum(p_scr[...] / l_safe, axis=0) / h
+        mass_ref[0] = jnp.sum(p_scr[...] / l_safe, axis=0, keepdims=True) / h
 
 
 def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
@@ -146,7 +146,10 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
         ],
         out_specs=[
             pl.BlockSpec((1, h, d), lambda bi, pi, pt, ln: (bi, 0, 0)),
-            pl.BlockSpec((1, n_pages), lambda bi, pi, pt, ln: (bi, 0)),
+            # the mass is emitted as [B, 1, n_pages] so the block's last
+            # two dims equal the array's: a (1, n_pages) block of a
+            # [B, n_pages] array breaks the TPU's (8, 128) tiling rule
+            pl.BlockSpec((1, 1, n_pages), lambda bi, pi, pt, ln: (bi, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((h, 1), jnp.float32),
@@ -155,13 +158,14 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
             pltpu.VMEM((h, n_pages), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out, mass = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, h, d), q.dtype),
-                   jax.ShapeDtypeStruct((b, n_pages), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, 1, n_pages), jnp.float32)],
         interpret=interpret,
     )(page_table, lengths, q, k_pages, v_pages)
+    return out, mass[:, 0]
 
 
 def _mla_kernel(page_table, lengths, qa_ref, qr_ref, ckv_ref, kr_ref,
@@ -224,7 +228,7 @@ def _mla_kernel(page_table, lengths, qa_ref, qr_ref, ckv_ref, kr_ref,
     def _flush():
         l_safe = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
-        mass_ref[0] = jnp.sum(p_scr[...] / l_safe, axis=0) / h
+        mass_ref[0] = jnp.sum(p_scr[...] / l_safe, axis=0, keepdims=True) / h
 
 
 def paged_attention_mla(q_abs, q_rope, ckv_pages, krope_pages, page_table,
@@ -255,7 +259,7 @@ def paged_attention_mla(q_abs, q_rope, ckv_pages, krope_pages, page_table,
         ],
         out_specs=[
             pl.BlockSpec((1, h, rdim), lambda bi, pi, pt, ln: (bi, 0, 0)),
-            pl.BlockSpec((1, n_pages), lambda bi, pi, pt, ln: (bi, 0)),
+            pl.BlockSpec((1, 1, n_pages), lambda bi, pi, pt, ln: (bi, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((h, 1), jnp.float32),
@@ -264,10 +268,11 @@ def paged_attention_mla(q_abs, q_rope, ckv_pages, krope_pages, page_table,
             pltpu.VMEM((h, n_pages), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out, mass = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, h, rdim), q_abs.dtype),
-                   jax.ShapeDtypeStruct((b, n_pages), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, 1, n_pages), jnp.float32)],
         interpret=interpret,
     )(page_table, lengths, q_abs, q_rope, ckv_pages, krope_pages)
+    return out, mass[:, 0]

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this produces, with zero device allocation:
@@ -19,6 +16,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import pathlib
 import re
 import time
@@ -242,6 +240,9 @@ def run_cell(arch, shape, mesh_mode, force=False):
 
 
 def main():
+    # 512 fake host devices for the production meshes; set before the
+    # first device query initialises the CPU backend
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as C
+from repro import compile_cache
 from repro.ckpt import checkpoint as ckpt
 from repro.data.pipeline import DataConfig, batch_at
 from repro.distributed import sharding as SH
@@ -53,6 +54,7 @@ def build(argv=None):
 
 def main(argv=None):
     args = build(argv)
+    compile_cache.enable()
     cfg = C.reduced(args.arch) if args.reduced else C.get(args.arch)
     ocfg = optim.OptConfig(lr=args.lr, warmup_steps=max(2, args.steps // 20),
                            decay_steps=args.steps)

@@ -82,14 +82,19 @@ def init(key, cfg: ModelConfig) -> Tuple[Params, Params]:
             kind = parse_kind(kind_s)
             kseed = jax.random.fold_in(ks[si + 2], j)
 
+            slot_specs = []
+
             def one(k):
-                return _slot_init(k, cfg, kind)[0]
+                # the spec tree is plain Python: read it off the traced
+                # init rather than allocating another layer to get it
+                p, s = _slot_init(k, cfg, kind)
+                slot_specs.append(s)
+                return p
 
             stacked = jax.vmap(one)(jax.random.split(kseed, repeats))
-            _, spec = _slot_init(kseed, cfg, kind)
             spec = jax.tree.map(
                 lambda ax: ("layers",) + tuple(ax) if isinstance(ax, tuple)
-                else ax, spec,
+                else ax, slot_specs[0],
                 is_leaf=lambda x: isinstance(x, tuple) or x is None)
             slot_ps.append(stacked)
             slot_ss.append(spec)
@@ -879,7 +884,7 @@ def row_cache_from_batched(cache, cfg: ModelConfig, bi: int, length: int,
 
 def decode_step_paged(params, cfg: ModelConfig, kv, tables, gid_tables,
                       tokens, cur_pos, *, page_size: int,
-                      impl: str = "reference", cond=None, state_cols=None,
+                      impl: Optional[str] = None, cond=None, state_cols=None,
                       mesh=None, shard=_IDENT):
     """One decode step with EVERY state-bearing layer reading and writing
     the shared paged pools -- the fully-paged serving hot path (no dense
@@ -919,10 +924,14 @@ def decode_step_paged(params, cfg: ModelConfig, kv, tables, gid_tables,
 
 
 def _paged_decode_core(params, cfg: ModelConfig, kv, tables, gid_tables,
-                       tokens, cur_pos, *, page_size: int, impl: str,
-                       cond=None, state_cols=None, mesh=None, shard=_IDENT):
+                       tokens, cur_pos, *, page_size: int,
+                       impl: Optional[str], cond=None, state_cols=None,
+                       mesh=None, shard=_IDENT):
     """The traced body shared by ``decode_step_paged`` (one launch per
-    token) and ``decode_macro_step`` (one launch per movement period)."""
+    token) and ``decode_macro_step`` (one launch per movement period).
+    ``impl=None`` takes the paged kernels' path from the platform: the
+    compiled Pallas kernels on a TPU, the jnp reference on the CPU."""
+    impl = impl or ops.default_impl("reference")
     b = tokens.shape[0]
     n_row_pages = tables.shape[1]
     active = cur_pos >= 0
@@ -1150,7 +1159,7 @@ def _sample_row(logits_row, key, temperature):
 def decode_macro_step(params, cfg: ModelConfig, kv, tables, gid_tables,
                       tokens, cur_pos, keys, iters, emitted, max_new,
                       eos_ids, temps, *, n_steps: int, page_size: int,
-                      impl: str = "reference", cond=None, state_cols=None,
+                      impl: Optional[str] = None, cond=None, state_cols=None,
                       mesh=None, shard=_IDENT):
     """Up to ``n_steps`` fully-paged decode steps in ONE device launch.
 

@@ -25,11 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.distributed import compat as _compat  # jax.shard_map on 0.4.x
 from repro.models.config import ModelConfig
 from repro.models.layers import _dense_init, split_tree
-
-_compat.install()
 
 Params = Dict[str, Any]
 

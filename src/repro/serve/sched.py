@@ -75,7 +75,24 @@ from repro.serve import engine as E
 from repro.serve.pipeline import DecisionWorker
 
 __all__ = ["Request", "TrafficMonitor", "ContinuousBatcher",
-           "TrafficScheduler", "WORKLOAD_KINDS"]
+           "TrafficScheduler", "WORKLOAD_KINDS", "decode_macro"]
+
+# The served programs take ``params`` as an argument -- closed over, every
+# weight would be embedded in the program as a constant -- and ``cfg`` as
+# a static one, so every batcher in the process shares one compiled
+# program per shape.  The kv pytree is dead after a decode call (the
+# caller publishes the returned one): donating it lets XLA update the
+# pool buffers in place instead of copying the whole layered store.
+_prefill_batched = jax.jit(mdl.prefill_batched, static_argnums=(1,))
+_prefill_chunk = jax.jit(mdl.prefill_chunk, static_argnums=(1,),
+                         static_argnames=("start",))
+_decode_paged = jax.jit(mdl.decode_step_paged, static_argnums=(1,),
+                        static_argnames=("page_size", "impl"),
+                        donate_argnums=(2,))
+#: the macro-step decode program (one launch per movement period)
+decode_macro = jax.jit(mdl.decode_macro_step, static_argnums=(1,),
+                       static_argnames=("n_steps", "page_size", "impl"),
+                       donate_argnums=(2,))
 
 
 # ---------------------------------------------------------------------------
@@ -408,7 +425,6 @@ class ContinuousBatcher:
                  monitor: Optional[TrafficMonitor] = None,
                  mirror_pages: bool = False,
                  paged: Optional[bool] = None,
-                 paged_impl: str = "reference",
                  macro: Optional[bool] = None,
                  macro_steps: Optional[int] = None,
                  pipeline: bool = False,
@@ -491,12 +507,11 @@ class ContinuousBatcher:
             and monitor is not None and monitor.pools.k_host is not None
         self._batched_prefill = mdl.batched_prefill_supported(cfg)
         if self._batched_prefill:
-            # admission prefills were dispatched eagerly (op-by-op) -- on
-            # the serving path that dwarfed the decode itself.  Jit it;
-            # prompt lengths are pow2-bucketed in _prefill so the compile
-            # cache is bounded (causal padding cannot change valid rows)
-            self._prefill_fn = jax.jit(functools.partial(
-                mdl.prefill_batched, params, cfg))
+            # admission prefills run jitted; prompt lengths are
+            # pow2-bucketed in _prefill so the compile cache is bounded
+            # (causal padding cannot change valid rows)
+            self._prefill_fn = functools.partial(_prefill_batched, params,
+                                                 cfg)
 
         # macro-launch straggler detection (the serving twin of the
         # training loop's step timer); its name routes flags and the
@@ -549,7 +564,6 @@ class ContinuousBatcher:
         self._pending_admits: List[_PendingAdmit] = []
         self._prefetched_next = 0
         self._decision_gen: Optional[int] = None
-        self._chunk_fns: Dict[int, Callable] = {}
         self._decision_worker = (DecisionWorker(self._plan_decision)
                                  if self.pipeline else None)
 
@@ -567,16 +581,8 @@ class ContinuousBatcher:
             self._state_cols = (jnp.full((max_active,), self.n_row_pages - 1,
                                          jnp.int32)
                                 if self._has_state else None)
-            # the kv pytree is dead after the call (set_kv replaces it):
-            # donate it so XLA updates the pool buffers in place instead
-            # of copying the whole layered store every step
-            self._paged_fn = jax.jit(functools.partial(
-                mdl.decode_step_paged, params, cfg,
-                page_size=page_size, impl=paged_impl), donate_argnums=(0,))
-            self._paged_impl = paged_impl
-            # one compiled macro per scan length (bounded: lengths are the
-            # tuner's period ladder, pow2-capped by the remaining work)
-            self._macro_fns: Dict[int, Callable] = {}
+            self._paged_fn = functools.partial(_decode_paged, params, cfg,
+                                               page_size=page_size)
             # shared read-only prefix: allocated + prefilled ONCE; every
             # request's table maps these pages, admission never
             # re-prefills the prefix
@@ -1250,14 +1256,11 @@ class ContinuousBatcher:
         return emitted
 
     def _macro_fn(self, n_steps: int):
-        fn = self._macro_fns.get(n_steps)
-        if fn is None:
-            fn = jax.jit(functools.partial(
-                mdl.decode_macro_step, self.params, self.cfg,
-                page_size=self.page_size, impl=self._paged_impl,
-                n_steps=n_steps), donate_argnums=(0,))
-            self._macro_fns[n_steps] = fn
-        return fn
+        """The macro program for one scan length (one compile per length:
+        lengths are the tuner's period ladder, pow2-capped by the
+        remaining work)."""
+        return functools.partial(decode_macro, self.params, self.cfg,
+                                 page_size=self.page_size, n_steps=n_steps)
 
     def _step_paged_macro(self) -> List[Tuple[int, int]]:
         """Macro-step decode: ONE device launch runs up to a movement
@@ -1737,12 +1740,8 @@ class ContinuousBatcher:
     def _chunk_fn(self, start: int) -> Callable:
         """Jitted ``prefill_chunk`` per (static) chunk start; the
         compile cache is bounded by ``max_len / chunk_width``."""
-        fn = self._chunk_fns.get(start)
-        if fn is None:
-            fn = jax.jit(functools.partial(mdl.prefill_chunk, self.params,
-                                           self.cfg, start=start))
-            self._chunk_fns[start] = fn
-        return fn
+        return functools.partial(_prefill_chunk, self.params, self.cfg,
+                                 start=start)
 
     def _dispatch_chunk(self, p: _PendingAdmit) -> None:
         """Dispatch ONE bounded chunk of a long-prompt admission: a
@@ -1925,7 +1924,7 @@ class ContinuousBatcher:
                 v = c["v"][-1, req.row, p * ps: (p + 1) * ps]
                 self.monitor.pools.write_page(int(req.gids[p]), k, v)
 
-    def paged_context(self, rid: int, q, *, impl: str = "interpret"):
+    def paged_context(self, rid: int, q, *, impl: Optional[str] = None):
         """Monitor-layer attention context for one in-flight request,
         gathered by ``kernels.paged_attention`` *from the shared HBM pool*
         through the request's page table (``slot_of`` indirection).  Pages

@@ -27,13 +27,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.distributed import compat as _compat  # jax.shard_map on 0.4.x
 from repro.distributed.collectives import compressed_psum
 from repro.models import model as mdl
 from repro.models.config import ModelConfig
 from repro.train import optim
-
-_compat.install()
 
 IGNORE = -1
 

@@ -31,8 +31,9 @@ def test_sharding_rules_resolution():
     out = _run("""
         import jax
         from repro.distributed import sharding as SH
-        from jax.sharding import PartitionSpec as P
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType, PartitionSpec as P
+        auto = lambda n: (AxisType.Auto,) * n
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=auto(2))
         # qwen-style: 40 heads don't divide 4 -> head_dim fallback
         s = SH.param_spec(("embed", "heads", "head_dim"), (64, 39, 128), mesh)
         assert s == P("data", None, "model"), s
@@ -41,7 +42,8 @@ def test_sharding_rules_resolution():
         s = SH.param_spec(("vocab", "embed"), (1000, 64), mesh)
         assert s == P("model", "data"), s
         # batch over (pod, data) with joint divisibility
-        mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                              axis_types=auto(3))
         # SP: seq shards over model when divisible
         s = SH.act_spec(("batch", "seq", "embed"), (8, 16, 64), mesh3)
         assert s == P(("pod", "data"), "model", None), s
@@ -58,12 +60,14 @@ def test_moe_shard_map_matches_dense_oracle():
     out = _run("""
         import dataclasses, jax, jax.numpy as jnp, numpy as np
         import repro.configs as C
+        from jax.sharding import AxisType
         from repro.models import moe as M
         cfg = dataclasses.replace(
             C.reduced("olmoe-1b-7b"),
             moe=dataclasses.replace(C.reduced("olmoe-1b-7b").moe,
                                     capacity_factor=8.0))  # no drops
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         p, _ = M.moe_init(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model),
                               jnp.float32)
@@ -81,10 +85,10 @@ def test_moe_shard_map_matches_dense_oracle():
 def test_compressed_psum_numerics():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AxisType, PartitionSpec as P
         from repro.distributed.collectives import (compressed_psum,
                                                    compressed_psum_ef)
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
         x = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
 
         def f(xs):

@@ -43,3 +43,16 @@ def test_hang_restarts_exhaust(tmp_path):
     assert report.exit_code == -9
     assert report.restarts == 1
     assert report.history == [-9, -9]
+
+
+def test_supervisor_parent_stays_off_jax():
+    """The supervising parent never imports JAX: a parent that touches it
+    would hold the chip its child needs."""
+    import subprocess
+    out = subprocess.run(
+        [sys.executable, "-c", "import sys, repro.ft.supervisor; "
+         "print('jax' in sys.modules)"],
+        capture_output=True, text=True, timeout=120,
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "False"

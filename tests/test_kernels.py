@@ -185,3 +185,22 @@ def test_paged_attention_page_permutation_invariance():
     o2 = ops.paged_attention(q, kp[perm], vp[perm], inv[pt], lengths,
                              impl="interpret")
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# kernel path chosen from the platform
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("backend,cpu,want", [
+    ("tpu", "interpret", "pallas"), ("tpu", "reference", "pallas"),
+    ("cpu", "interpret", "interpret"), ("cpu", "reference", "reference")])
+def test_default_impl_follows_the_platform(monkeypatch, backend, cpu, want):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops.default_impl(cpu) == want
+
+
+def test_default_impl_refuses_an_unknown_backend(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(ValueError, match="no kernel path"):
+        ops.default_impl("interpret")

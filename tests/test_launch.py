@@ -64,3 +64,26 @@ def test_train_overrides_cover_heavy_archs():
     from repro.launch.dryrun import TRAIN_OVERRIDES
     assert TRAIN_OVERRIDES["nemotron-4-340b"]["state_dtype"] == "bfloat16"
     assert TRAIN_OVERRIDES["deepseek-v3-671b"]["accum"] >= 4
+
+
+def test_compile_cache_defaults_to_a_fixed_dir_in_the_checkout(monkeypatch):
+    from repro import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        path = compile_cache.enable()
+        assert path == str(compile_cache.DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.DEFAULT_DIR.parent.joinpath(
+            "src", "repro", "compile_cache.py").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_env_var_wins_and_nothing_else_is_set(monkeypatch,
+                                                           tmp_path):
+    from repro import compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    was = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == was

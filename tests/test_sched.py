@@ -496,7 +496,9 @@ def test_admission_prefills_in_one_packed_pass(monkeypatch):
     params, _ = mdl.init(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(2)
     calls = {"batched": 0, "single": 0}
-    orig_b, orig_1 = mdl.prefill_batched, mdl.prefill
+    # the batcher dispatches the module's jitted packed prefill: count
+    # dispatches (a trace-time count would miss a cached compile)
+    orig_b, orig_1 = S._prefill_batched, mdl.prefill
 
     def count_b(*a, **k):
         calls["batched"] += 1
@@ -506,7 +508,7 @@ def test_admission_prefills_in_one_packed_pass(monkeypatch):
         calls["single"] += 1
         return orig_1(*a, **k)
 
-    monkeypatch.setattr(mdl, "prefill_batched", count_b)
+    monkeypatch.setattr(S, "_prefill_batched", count_b)
     monkeypatch.setattr(mdl, "prefill", count_1)
     b = S.ContinuousBatcher(params, cfg, max_active=3, max_len=32,
                             page_size=4,
