@@ -523,6 +523,7 @@ def serving_perf(quick: bool = False) -> Dict:
         submit_wave(b, 0)                    # warm the jit caches
         drive(b)
         n_admits = len(rec.events("serve.admit"))
+        n_spans = len(rec.events("obs.span"))
         submit_wave(b, 1)                    # timed wave
         t0 = time.perf_counter()
         tokens, lats = drive(b)
@@ -540,11 +541,13 @@ def serving_perf(quick: bool = False) -> Dict:
             "latency_ms_p95": float(np.percentile(lat_ms, 95)),
         }
         # p95 admission stall over the timed wave, from the flight
-        # recorder's serve.admit walls: reservation-to-activation for
-        # the pipelined loop (stall_ms), prefill dispatch wall for the
+        # recorder: reservation-to-activation for the pipelined loop
+        # (serve.admit's stall_ms), the serve.prefill span for the
         # synchronous paths (admission is inline there)
         admits = rec.events("serve.admit")[n_admits:]
-        stalls = [e.get("stall_ms", e["wall_ms"]) for e in admits]
+        stalls = [e["stall_ms"] for e in admits if "stall_ms" in e] or [
+            e["ms"] for e in rec.events("obs.span")[n_spans:]
+            if e["name"] == "serve.prefill"]
         if stalls:
             results[mode]["admission_stall_ms_p95"] = float(
                 np.percentile(np.asarray(stalls), 95))

@@ -534,9 +534,10 @@ class SharedPagedPools:
         never traded away), but the pages pin to host for a cooldown and
         the fetch is counted in ``degraded_fetches`` so the serving loop
         can charge it at ``miss_penalty`` into the tuner's window."""
-        slots, missing = self._place(gids)
-        if missing.size:
-            self._migrate_with_retry(slots, missing)
+        with _obs.RECORDER.span("pool.ensure_resident"):
+            slots, missing = self._place(gids)
+            if missing.size:
+                self._migrate_with_retry(slots, missing)
         if missing.size and (r := _obs.RECORDER).enabled:
             r.count("pool.fetch_misses", int(missing.size))
             r.gauge("pool.hbm_resident_frac",

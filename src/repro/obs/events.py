@@ -27,7 +27,7 @@ RESERVED_FIELDS = ("seq", "t", "type")
 class Event(NamedTuple):
     """One registered event type: its field names and what it records."""
     name: str
-    domain: str                 # tuner | tier | pool | serve | ft | meta
+    domain: str                 # tuner | tier | pool | serve | ft | obs | meta
     fields: Tuple[str, ...]
     description: str
 
@@ -98,11 +98,14 @@ _ALL = [
         "planes one page migration moves"),
     # -- serve: the continuous-batching scheduler (wall clock) ---------------
     _ev("serve.admit",
-        ("step", "joiners", "pages", "queue_depth", "wall_ms", "stall_ms"),
+        ("step", "joiners", "pages", "queue_depth", "rids", "wait_ms",
+         "stall_ms"),
         "one admission batch: requests packed-prefilled together, pages "
-        "allocated, queue depth after, prefill wall time; the pipelined "
-        "loop adds stall_ms, the batch's worst reservation-to-activation "
-        "admission stall (the SLO the chunk knob trades against)"),
+        "allocated, queue depth after, the joiners' rids and each one's "
+        "queue wait (submit to the start of its admission); the "
+        "pipelined loop adds stall_ms, the batch's worst "
+        "reservation-to-activation admission stall (the SLO the chunk "
+        "knob trades against)"),
     _ev("serve.retire",
         ("step", "rid", "tokens", "status", "deadline_ms"),
         "a request left the system with a typed terminal status -- "
@@ -134,11 +137,6 @@ _ALL = [
     _ev("serve.stream",
         ("phase", "tokens", "wall_ms"),
         "single-stream monitored_generate started/finished"),
-    _ev("serve.pipeline.stage",
-        ("step", "stage", "wall_ms"),
-        "one overlap-window stage of the pipelined macro loop finished "
-        "behind the in-flight scan: decision_wait, prefetch, tables or "
-        "admit"),
     _ev("serve.pipeline.decision",
         ("step", "generation", "period", "bring", "evict", "wait_ms"),
         "a background-worker tiering/tuner decision was applied at a "
@@ -161,6 +159,13 @@ _ALL = [
         "logical clock, this kind's occurrence counter and the point's "
         "magnitude parameter (chaos runs replay deterministically from "
         "the plan seed)"),
+    # -- obs: the recorder's own spans (wall clock) ---------------------------
+    _ev("obs.span",
+        ("name", "parent", "ms"),
+        "a Recorder.span closed: its name, the enclosing span's name on "
+        "the same thread (empty at the top), its wall milliseconds, and "
+        "any fields the caller gave; the same span is on the profiler's "
+        "host plane when a trace is recording"),
     # -- meta: records written by the exporters, never emit()ed --------------
     _ev("metrics.summary",
         ("schema", "counters", "gauges", "hists"),

@@ -10,8 +10,9 @@ in https://ui.perfetto.dev:
     PROFILE/TRIAL/HOLD phases render as named spans (ts = step, 1 step
     = 1 us), one thread per tiering manager whose inter-tier windows
     render as ``window(p=N)`` spans, plus a ``period`` counter track.
-  * pid 2, "serving (wall clock)": macro-step launches and admission
-    batches as duration spans at their measured wall times, plus a
+  * pid 2, "serving (wall clock)": macro-step launches on one thread
+    and ``Recorder.span`` spans on another, as duration spans at their
+    measured wall times; admission batches as instants, plus a
     ``queue_depth`` counter track.
 
 Guard trips / window extensions / retirements are instant events on
@@ -98,6 +99,7 @@ def perfetto_trace(events: Iterable[dict]) -> Dict[str, Any]:
     for who, tid in mgr_tids.items():
         te.append(_meta(_STEP_PID, tid, f"tiering {who}"))
     te.append(_meta(_WALL_PID, 1, "scheduler"))
+    te.append(_meta(_WALL_PID, 2, "spans"))
 
     # -- tuner phase spans: each transition closes the previous phase -------
     open_phase: Dict[str, tuple] = {}        # tuner -> (state, since_step)
@@ -169,14 +171,19 @@ def perfetto_trace(events: Iterable[dict]) -> Dict[str, Any]:
     for ev in events:
         typ = ev["type"]
         ts = float(ev.get("t", 0.0)) * 1e6
-        if typ in ("serve.macro", "serve.admit"):
-            dur = max(1.0, float(ev.get("wall_ms", 0.0)) * 1e3)
-            name = (f"macro x{ev.get('n_steps')}" if typ == "serve.macro"
-                    else f"admit x{ev.get('joiners')}")
+        if typ in ("serve.macro", "obs.span"):
+            macro = typ == "serve.macro"
+            dur = max(1.0, float(ev["wall_ms" if macro else "ms"]) * 1e3)
             args = {k: v for k, v in ev.items()
                     if k not in ("seq", "t", "type")}
-            te.append({"name": name, "ph": "X", "ts": ts - dur, "dur": dur,
-                       "pid": _WALL_PID, "tid": 1, "args": args})
+            te.append({"name": f"macro x{ev.get('n_steps')}" if macro
+                       else ev["name"], "ph": "X", "ts": ts - dur,
+                       "dur": dur, "pid": _WALL_PID, "tid": 1 if macro else 2,
+                       "args": args})
+        elif typ == "serve.admit":
+            te.append({"name": f"admit x{ev.get('joiners')}", "ph": "i",
+                       "ts": ts, "pid": _WALL_PID, "tid": 1, "s": "t",
+                       "args": {"rids": ev.get("rids")}})
         elif typ == "serve.retire":
             te.append({"name": f"retire rid={ev.get('rid')}", "ph": "i",
                        "ts": ts, "pid": _WALL_PID, "tid": 1, "s": "t",

@@ -87,7 +87,7 @@ def _line_other(ev: dict) -> Optional[str]:
     if typ == "serve.admit":
         return (f"t {ev['t']:10.3f}s  admit x{ev['joiners']} "
                 f"({ev['pages']} pages, queue {ev['queue_depth']}, "
-                f"{_fmt(ev.get('wall_ms'), 2)} ms)")
+                f"rids {ev.get('rids')})")
     if typ == "serve.macro":
         flag = "  ** straggler" if ev.get("straggler") else ""
         return (f"t {ev['t']:10.3f}s  macro x{ev['n_steps']}: "

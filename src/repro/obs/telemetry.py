@@ -14,12 +14,18 @@ with ``install``) collects everything the instrumented stack emits:
   * **Histograms** -- streaming fixed-geometric-bucket quantile sketches
     (``observe``): bounded memory, ~9% relative quantile error
     (``ratio = 2**0.25`` buckets), exact count/sum/min/max.
+  * **Spans** -- ``with RECORDER.span(name):`` times a block.  It opens
+    a ``jax.profiler.TraceAnnotation`` of the same name, so a running
+    profiler records the span on its host plane, on the device trace's
+    clock, and on exit emits one ``obs.span`` event (its duration and the
+    name of the enclosing span on the same thread).
 
 Hot-path contract: instrumented code guards every emission with
 ``if (r := RECORDER).enabled:`` so a disabled recorder costs one
 attribute load and one branch -- no kwargs dict, no event record, zero
 allocations.  ``emit`` itself also checks, so un-guarded call sites are
-merely slower, never wrong.
+merely slower, never wrong.  ``span`` needs no guard: disabled, it
+returns one shared no-op context (no annotation, no event).
 
 The recorder is multi-writer: the pipelined serving loop emits from both
 the dispatch thread and the background decision worker, so every mutation
@@ -32,6 +38,7 @@ a consistent prefix at worst.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import os
 import threading
@@ -43,6 +50,9 @@ import numpy as np
 from repro.obs.events import EVENTS
 
 __all__ = ["Histogram", "Recorder", "RECORDER", "install", "get"]
+
+#: what a disabled recorder's ``span`` returns: one shared no-op context
+_NO_SPAN = contextlib.nullcontext()
 
 
 class Histogram:
@@ -147,6 +157,8 @@ class Recorder:
         # serialises writers: the pipelined serving loop emits from the
         # dispatch thread AND the background decision worker
         self._lock = threading.Lock()
+        # per-thread stack of open span names (a span's parent)
+        self._open = threading.local()
 
     # -- events --------------------------------------------------------------
     def emit(self, etype: str, **fields: Any) -> None:
@@ -162,6 +174,20 @@ class Recorder:
             self._ring[seq % self.capacity] = (
                 seq, time.monotonic() - self._t0, etype, fields)
             self._seq = seq + 1
+
+    def span(self, name: str, **fields: Any):
+        """Context manager timing its block as the span ``name`` (see the
+        module docstring); ``fields`` ride on its ``obs.span`` event.
+        Disabled, the shared no-op context."""
+        if not self.enabled:
+            return _NO_SPAN
+        return _Span(self, name, fields)
+
+    def _span_stack(self) -> List[str]:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
 
     @property
     def dropped(self) -> int:
@@ -231,6 +257,32 @@ class Recorder:
             self.gauges.clear()
             self.hists.clear()
             self._t0 = time.monotonic()
+
+
+class _Span:
+    """One open span of an enabled ``Recorder`` (``Recorder.span``)."""
+
+    __slots__ = ("rec", "name", "fields", "parent", "ann", "t0")
+
+    def __init__(self, rec: Recorder, name: str, fields: Dict[str, Any]):
+        self.rec, self.name, self.fields = rec, name, fields
+
+    def __enter__(self) -> "_Span":
+        from jax.profiler import TraceAnnotation
+        stack = self.rec._span_stack()
+        self.parent = stack[-1] if stack else ""
+        stack.append(self.name)
+        self.ann = TraceAnnotation(self.name)
+        self.ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        ms = (time.monotonic() - self.t0) * 1e3
+        self.ann.__exit__(*exc)
+        self.rec._span_stack().pop()
+        self.rec.emit("obs.span", name=self.name, parent=self.parent, ms=ms,
+                      **self.fields)
 
 
 #: The process-global recorder every instrumented module reads through

@@ -178,16 +178,20 @@ class TrafficMonitor:
             # around the failing pages instead of seeing them as cheap
             mgr.modeled_time += degraded * max(
                 0.0, mgr.cfg.miss_penalty - mgr.cfg.fetch_cost)
-        mgr.on_step(global_mass, self.pools.resident_mask,
-                    weight=float(n_tokens or 1))
-        mgr.maybe_tier(self.pools, active=self.pools.allocated_mask,
-                       force=force_tier)
+        rec = _obs.RECORDER
+        with rec.span("tier.account"):
+            mgr.on_step(global_mass, self.pools.resident_mask,
+                        weight=float(n_tokens or 1))
+        with rec.span("tier.maybe_tier"):
+            mgr.maybe_tier(self.pools, active=self.pools.allocated_mask,
+                           force=force_tier)
         if self.tuner is not None:
             cost = mgr.modeled_time - before
             if n_active is not None:
                 cost /= max(1, n_active)
-            mgr.set_period(self.tuner.on_step(global_mass, cost=cost,
-                                              dt=n_tokens or 1))
+            with rec.span("tuner.on_step"):
+                mgr.set_period(self.tuner.on_step(global_mass, cost=cost,
+                                                  dt=n_tokens or 1))
         return mgr.period
 
     def on_macro_step(self, global_mass: np.ndarray,
@@ -313,7 +317,7 @@ class Request:
     # the int() download, the tokens append, the emit -- is deferred to
     # the next macro boundary so activation never blocks the launch
     _first_tok: object = None
-    _t_submit: float = 0.0             # wall clock at submit (deadline_ms)
+    _t_submit: float = 0.0             # wall clock at submit (queue wait)
     # preemption freeze-frame: the row state saved when the request is
     # frozen (pages stay allocated host-side; _key/_i live on the
     # request already, so reactivation is a pure row re-install)
@@ -342,7 +346,7 @@ class _PendingAdmit:
     chunk_idx: int = 0
     logits: object = None        # lazy [1, 1, V] first-token logits
     ready: bool = False
-    t_submit: float = 0.0
+    t_reserved: float = 0.0      # wall clock at reservation
 
 
 class ContinuousBatcher:
@@ -725,40 +729,43 @@ class ContinuousBatcher:
         self.queue = collections.deque(keep)
 
     def _admit(self) -> List[Tuple[int, int]]:
-        self._expire_queue()
         batch: List[Request] = []
-        while self.queue and self.rows_free:
-            req = self.queue[0]
-            n_exact = self._pages_exact(req)
-            n_alloc = self._pages_alloc(req)
-            gids = None
-            if self.monitor is not None:
-                # the gate runs against the EFFECTIVE capacity (equal to
-                # hbm_pages unless a squeeze fault shrank it), so new
-                # admissions respect the degraded budget
-                if self.paged and (self._hbm_need + n_exact
-                                   > self.monitor.pools.effective_hbm):
-                    break              # head-of-line: keep arrival order
-                gids = self.monitor.pools.alloc(n_alloc, req.rid)
-                if gids is None:       # head-of-line: keep arrival order
-                    break
-            self.queue.popleft()
-            row = self.rows_free.pop()
-            req.row, req.gids, req.n_pages = row, gids, n_exact
-            req.n_alloc = n_alloc
-            if self.paged:
-                self._hbm_need += n_exact
-                self._map_row(req)
-            batch.append(req)
+        t_admit = time.monotonic()         # the joiners' queue wait ends
+        with _obs.RECORDER.span("serve.admit"):
+            self._expire_queue()
+            while self.queue and self.rows_free:
+                req = self.queue[0]
+                n_exact = self._pages_exact(req)
+                n_alloc = self._pages_alloc(req)
+                gids = None
+                if self.monitor is not None:
+                    # the gate runs against the EFFECTIVE capacity (equal
+                    # to hbm_pages unless a squeeze fault shrank it), so
+                    # new admissions respect the degraded budget
+                    if self.paged and (self._hbm_need + n_exact
+                                       > self.monitor.pools.effective_hbm):
+                        break          # head-of-line: keep arrival order
+                    gids = self.monitor.pools.alloc(n_alloc, req.rid)
+                    if gids is None:   # head-of-line: keep arrival order
+                        break
+                self.queue.popleft()
+                row = self.rows_free.pop()
+                req.row, req.gids, req.n_pages = row, gids, n_exact
+                req.n_alloc = n_alloc
+                if self.paged:
+                    self._hbm_need += n_exact
+                    self._map_row(req)
+                batch.append(req)
         if not batch:
             return []
-        t0 = time.monotonic()
-        emitted = self._prefill(batch)
+        with _obs.RECORDER.span("serve.prefill", joiners=len(batch)):
+            emitted = self._prefill(batch)
         if (r := _obs.RECORDER).enabled:
             r.emit("serve.admit", step=self.step_idx, joiners=len(batch),
                    pages=int(sum(b.n_alloc for b in batch)),
                    queue_depth=len(self.queue),
-                   wall_ms=(time.monotonic() - t0) * 1e3)
+                   rids=[b.rid for b in batch],
+                   wait_ms=[(t_admit - b._t_submit) * 1e3 for b in batch])
             r.count("serve.admitted", len(batch))
             r.gauge("serve.queue_depth", len(self.queue))
         return emitted
@@ -817,15 +824,16 @@ class ContinuousBatcher:
         the key needs no row list."""
         pools = self.monitor.pools
         key = (int(getattr(pools, "slot_epoch", 0)), self._rows_epoch)
-        track = (r := _obs.RECORDER).enabled
+        r = _obs.RECORDER
         if self._tables_key == key and self._tables_dev is not None:
-            if track:
+            if r.enabled:
                 r.count("pool.table_upload.skipped")
             return self._tables_dev
-        self._tables_dev = (jnp.asarray(self._slot_table(rows)),
-                            jnp.asarray(self._gid_tables))
+        with r.span("serve.tables"):
+            self._tables_dev = (jnp.asarray(self._slot_table(rows)),
+                                jnp.asarray(self._gid_tables))
         self._tables_key = key
-        if track:
+        if r.enabled:
             r.count("pool.table_upload.performed")
         return self._tables_dev
 
@@ -851,22 +859,22 @@ class ContinuousBatcher:
             return np.asarray([], np.int64)
         return np.concatenate(need)
 
-    def _prefill(self, batch: List[Request]) -> List[Tuple[int, int]]:
-        """Prefill a step's joiners as one packed forward pass, seed their
-        rows/pages, and sample each first token."""
-        plens = [len(r.prompt) for r in batch]
-        if self._batched_prefill:
-            # pow2-bucket BOTH packed dims -- width and joiner count --
-            # so the jitted prefill (and the downstream page scatter)
-            # compiles per shape class, not per admission.  Right-padding
-            # is inert under causal attention and dummy joiner rows are
-            # simply never read, so valid rows are bit-identical.
+    def _launch_packed_prefill(self, prompts: List[np.ndarray]):
+        """Pad a batch of prompts and dispatch ONE packed prefill; returns
+        its lazy ``(logits, cache)``.  BOTH packed dims -- width and
+        joiner count -- are pow2-bucketed, so the jitted prefill (and the
+        downstream page scatter) compiles per shape class, not per
+        admission.  Right-padding is inert under causal attention and
+        dummy joiner rows are simply never read, so valid rows are
+        bit-identical."""
+        with _obs.RECORDER.span("serve.prefill.launch"):
+            plens = [len(p) for p in prompts]
             smax = bucket_pages(max(plens))
-            jp = bucket_pages(len(batch))
+            jp = bucket_pages(len(prompts))
             toks = np.zeros((jp, smax), np.int32)
             plens_p = np.ones((jp,), np.int32)
-            for i, r in enumerate(batch):
-                toks[i, : plens[i]] = r.prompt
+            for i, p in enumerate(prompts):
+                toks[i, : plens[i]] = p
                 # lengths INCLUDE the shared prefix: the last valid
                 # position of row i sits at prefix + plen - 1
                 plens_p[i] = self.prefix + plens[i]
@@ -877,8 +885,16 @@ class ContinuousBatcher:
             if self._ex is not None:
                 kw["extra_embeds"] = jnp.broadcast_to(
                     self._ex, (jp,) + self._ex.shape[1:])
-            logits_b, cache_b = self._prefill_fn(
-                jnp.asarray(toks), jnp.asarray(plens_p), **kw)
+            return self._prefill_fn(jnp.asarray(toks), jnp.asarray(plens_p),
+                                    **kw)
+
+    def _prefill(self, batch: List[Request]) -> List[Tuple[int, int]]:
+        """Prefill a step's joiners as one packed forward pass, seed their
+        rows/pages, and sample each first token."""
+        plens = [len(r.prompt) for r in batch]
+        if self._batched_prefill:
+            logits_b, cache_b = self._launch_packed_prefill(
+                [r.prompt for r in batch])
         else:               # recurrent state: one request at a time
             logits_b, cache_b = None, None
 
@@ -887,6 +903,13 @@ class ContinuousBatcher:
             # EVERY layer straight into the pool slots
             self._write_prefill_pages_batched(cache_b, batch, plens)
 
+        with _obs.RECORDER.span("serve.prefill.first_tokens"):
+            return self._first_tokens(batch, plens, logits_b, cache_b)
+
+    def _first_tokens(self, batch: List[Request], plens: List[int],
+                      logits_b, cache_b) -> List[Tuple[int, int]]:
+        """The per-joiner half of an admission: install each row, sample
+        its first token (a host sync each), retire one-token requests."""
         emitted: List[Tuple[int, int]] = []
         for bi, req in enumerate(batch):
             row, plen = req.row, plens[bi]
@@ -951,31 +974,32 @@ class ContinuousBatcher:
         Slots are assigned bookkeeping-only first (initial placement, not
         charged as misses) since the scatter overwrites both tiers --
         the prefill bytes never take the host detour."""
-        pools = self.monitor.pools
-        ps = self.page_size
-        # own token pages only: the prefix is page-aligned, so each
-        # prompt's pages start at cache position ``prefix``
-        ns = [-(-p // ps) for p in plens]
-        # both scatter dims pow2-bucketed (matching the prefill batch):
-        # padded joiner rows / tail pages carry PAGE_DROP and vanish
-        jp = cache_b["segments"][0][0]["pos"].shape[1]
-        n_max = bucket_pages(max(ns))
-        gids_m = np.full((jp, n_max), PAGE_DROP, np.int32)
-        slots_m = np.full((jp, n_max), PAGE_DROP, np.int32)
-        for i, (req, n) in enumerate(zip(batch, ns)):
-            gids_m[i, :n] = req.gids[:n]
-        flat = np.concatenate([req.gids[:n]
-                               for req, n in zip(batch, ns)])
-        slots_flat = pools.assign_slots(flat)
-        o = 0
-        for i, n in enumerate(ns):
-            slots_m[i, :n] = slots_flat[o: o + n]
-            o += n
-        leaves = self._prefill_leaves(cache_b, mdl.state_slot_meta(self.cfg),
-                                      self.prefix)
-        pools.set_kv(write_pages_batched(
-            pools.kv_view(), leaves, jnp.asarray(gids_m),
-            jnp.asarray(slots_m)))
+        with _obs.RECORDER.span("pool.write_prefill"):
+            pools = self.monitor.pools
+            ps = self.page_size
+            # own token pages only: the prefix is page-aligned, so each
+            # prompt's pages start at cache position ``prefix``
+            ns = [-(-p // ps) for p in plens]
+            # both scatter dims pow2-bucketed (matching the prefill batch):
+            # padded joiner rows / tail pages carry PAGE_DROP and vanish
+            jp = cache_b["segments"][0][0]["pos"].shape[1]
+            n_max = bucket_pages(max(ns))
+            gids_m = np.full((jp, n_max), PAGE_DROP, np.int32)
+            slots_m = np.full((jp, n_max), PAGE_DROP, np.int32)
+            for i, (req, n) in enumerate(zip(batch, ns)):
+                gids_m[i, :n] = req.gids[:n]
+            flat = np.concatenate([req.gids[:n]
+                                   for req, n in zip(batch, ns)])
+            slots_flat = pools.assign_slots(flat)
+            o = 0
+            for i, n in enumerate(ns):
+                slots_m[i, :n] = slots_flat[o: o + n]
+                o += n
+            leaves = self._prefill_leaves(
+                cache_b, mdl.state_slot_meta(self.cfg), self.prefix)
+            pools.set_kv(write_pages_batched(
+                pools.kv_view(), leaves, jnp.asarray(gids_m),
+                jnp.asarray(slots_m)))
 
     def _write_prefill_pages_row(self, cache1, req: Request,
                                  plen: int) -> None:
@@ -1079,22 +1103,23 @@ class ContinuousBatcher:
         if not self.paged or self.monitor is None:
             return
         pools = self.monitor.pools
-        while self._frozen and self.rows_free:
-            req = self._frozen[0]
-            fits = self._hbm_need + req.n_pages <= pools.effective_hbm
-            if not fits and (self.active or self._pending_admits):
-                break
-            self._thaw(self._frozen.pop(0))
-        while (self._hbm_need > pools.effective_hbm
-               and len(self.active) > 1):
-            hot = self.monitor.manager.hotness
-            victims = [req for req in self.active.values()
-                       if req._first_tok is None]
-            if len(victims) <= 1:
-                break
-            victim = min(victims,
-                         key=lambda q: (float(hot[q.gids].sum()), -q.rid))
-            self._preempt(victim)
+        with _obs.RECORDER.span("serve.rebalance"):
+            while self._frozen and self.rows_free:
+                req = self._frozen[0]
+                fits = self._hbm_need + req.n_pages <= pools.effective_hbm
+                if not fits and (self.active or self._pending_admits):
+                    break
+                self._thaw(self._frozen.pop(0))
+            while (self._hbm_need > pools.effective_hbm
+                   and len(self.active) > 1):
+                hot = self.monitor.manager.hotness
+                victims = [req for req in self.active.values()
+                           if req._first_tok is None]
+                if len(victims) <= 1:
+                    break
+                victim = min(victims, key=lambda q: (
+                    float(hot[q.gids].sum()), -q.rid))
+                self._preempt(victim)
 
     def _preempt(self, req: Request) -> None:
         """Freeze one active request: demote its own pages to host
@@ -1152,12 +1177,10 @@ class ContinuousBatcher:
         completes the PREVIOUS in-flight macro, launches the next one and
         fills the overlap window behind it, so tokens surface one step
         after their macro launched."""
-        track = (r := _obs.RECORDER).enabled
-        t0 = time.monotonic() if track else 0.0
-        self._fault_tick()
-        if self.pipeline:
-            emitted = self._step_pipelined()
-        else:
+        with _obs.RECORDER.span("serve.step"):
+            self._fault_tick()
+            if self.pipeline:
+                return self._step_pipelined()
             self._rebalance()
             emitted = self._admit()
             self.step_idx += 1
@@ -1167,9 +1190,7 @@ class ContinuousBatcher:
                                 else self._step_paged())
                 else:
                     emitted += self._step_dense()
-        if track:
-            r.observe("serve.step_s", time.monotonic() - t0)
-        return emitted
+            return emitted
 
     def _step_dense(self) -> List[Tuple[int, int]]:
         emitted: List[Tuple[int, int]] = []
@@ -1336,31 +1357,32 @@ class ContinuousBatcher:
         # when something actually changed since the last upload
         # (epoch-keyed cache; otherwise the staged buffer is swapped in)
         tables_dev, gids_dev = self._tables_for([row for row, _ in rows])
-        cur = np.full((self.max_active,), -1, np.int32)
-        keys = np.zeros((self.max_active, 2), np.uint32)
-        iters = np.zeros((self.max_active,), np.int32)
-        emitted_ct = np.zeros((self.max_active,), np.int32)
-        max_new = np.zeros((self.max_active,), np.int32)
-        eos = np.full((self.max_active,), -1, np.int32)
-        temps = np.zeros((self.max_active,), np.float32)
-        for row, req in rows:
-            cur[row] = pos_np[row]
-            keys[row] = np.asarray(req._key, np.uint32)
-            iters[row] = req._i
-            emitted_ct[row] = ect[row]
-            max_new[row] = req.max_new_tokens
-            eos[row] = -1 if req.eos_id is None else req.eos_id
-            temps[row] = req.temperature
+        with _obs.RECORDER.span("serve.macro.launch", n_steps=n_steps):
+            cur = np.full((self.max_active,), -1, np.int32)
+            keys = np.zeros((self.max_active, 2), np.uint32)
+            iters = np.zeros((self.max_active,), np.int32)
+            emitted_ct = np.zeros((self.max_active,), np.int32)
+            max_new = np.zeros((self.max_active,), np.int32)
+            eos = np.full((self.max_active,), -1, np.int32)
+            temps = np.zeros((self.max_active,), np.float32)
+            for row, req in rows:
+                cur[row] = pos_np[row]
+                keys[row] = np.asarray(req._key, np.uint32)
+                iters[row] = req._i
+                emitted_ct[row] = ect[row]
+                max_new[row] = req.max_new_tokens
+                eos[row] = -1 if req.eos_id is None else req.eos_id
+                temps[row] = req.temperature
 
-        n_flags = len(self.macro_timer.stragglers)
-        self.macro_timer.start()
-        toks, kv, st = self._macro_fn(n_steps)(
-            pools.kv_view(), tables_dev, gids_dev, self.tok,
-            jnp.asarray(cur), jnp.asarray(keys), jnp.asarray(iters),
-            jnp.asarray(emitted_ct), jnp.asarray(max_new),
-            jnp.asarray(eos), jnp.asarray(temps),
-            cond=self._cond_rows, state_cols=self._state_cols)
-        pools.set_kv(kv)
+            n_flags = len(self.macro_timer.stragglers)
+            self.macro_timer.start()
+            toks, kv, st = self._macro_fn(n_steps)(
+                pools.kv_view(), tables_dev, gids_dev, self.tok,
+                jnp.asarray(cur), jnp.asarray(keys), jnp.asarray(iters),
+                jnp.asarray(emitted_ct), jnp.asarray(max_new),
+                jnp.asarray(eos), jnp.asarray(temps),
+                cond=self._cond_rows, state_cols=self._state_cols)
+            pools.set_kv(kv)
         return {"toks": toks, "st": st, "rows": rows, "n_steps": n_steps,
                 "fetched": fetched, "degraded": degraded,
                 "n_flags": n_flags, "horizons": horizons, "pos_np": pos_np}
@@ -1377,11 +1399,12 @@ class ContinuousBatcher:
         touches (retire/release, activation) are done -- the worker's
         strict-alternation safety window."""
         st, rows, n_steps = fl["st"], fl["rows"], fl["n_steps"]
-        toks_np = np.asarray(fl["toks"])
-        mass_sum = np.asarray(st["mass_sum"])
-        alive_steps = np.asarray(st["alive_steps"])
-        stopped = np.asarray(st["stopped"])
-        iters_out = np.asarray(st["iters"])
+        with _obs.RECORDER.span("serve.macro.wait"):
+            toks_np = np.asarray(fl["toks"])
+            mass_sum = np.asarray(st["mass_sum"])
+            alive_steps = np.asarray(st["alive_steps"])
+            stopped = np.asarray(st["stopped"])
+            iters_out = np.asarray(st["iters"])
         # the downloads above force the device sync: the stop covers the
         # whole launch + transfer, which is what a straggler would slow
         macro_wall = self.macro_timer.stop(self.step_idx)
@@ -1392,65 +1415,67 @@ class ContinuousBatcher:
         # access threshold expects is preserved).  dt = the macro's span
         # in token-steps; the mean in-flight count normalises cost per
         # request as on the per-token path.
-        merged = self.monitor.merge(
-            [(r.table_gids,
-              mass_sum[r.row][r.mass_cols]
-              / max(1, int(alive_steps[r.row])))
-             for _, r in rows])
-        dt = max(1, int(alive_steps.max()))
-        n_active = float(alive_steps.sum()) / dt
-        if (plan := self.fault_plan).enabled \
-                and plan.fires("mass.nonfinite") is not None:
-            # corrupt the merged telemetry deterministically: the monitor
-            # feed's NaN clamp must neutralise it before the reuse
-            # collector / tuner see it (the defense this fault exercises)
-            merged[::3] = np.nan
-            merged[1::5] = np.inf
-        payload: Optional[Dict] = None
-        if sync:
-            self.monitor.on_macro_step(merged, n_active=n_active,
-                                       n_tokens=dt, fetched=fl["fetched"],
-                                       degraded=fl["degraded"])
-        else:
-            # boundary snapshots for the worker's plan (apply_plan
-            # revalidates against whatever moves before actuation).  The
-            # free-slot budget is clamped to the squeezed capacity so a
-            # worker-planned bring never overfills the effective pool.
-            pools = self.monitor.pools
-            n_free = int((pools.page_of_slot < 0).sum())
-            n_free = min(n_free, max(0, pools.effective_hbm
-                                     - pools.hbm_occupied))
-            payload = dict(global_mass=merged, n_active=n_active,
-                           n_tokens=dt, fetched=fl["fetched"],
-                           degraded=fl["degraded"],
-                           resident=pools.slot_of >= 0,
-                           n_free=n_free,
-                           active=pools.allocated_mask,
-                           planes=int(getattr(pools, "move_planes", 2)))
+        with _obs.RECORDER.span("serve.monitor"):
+            merged = self.monitor.merge(
+                [(r.table_gids,
+                  mass_sum[r.row][r.mass_cols]
+                  / max(1, int(alive_steps[r.row])))
+                 for _, r in rows])
+            dt = max(1, int(alive_steps.max()))
+            n_active = float(alive_steps.sum()) / dt
+            if (plan := self.fault_plan).enabled \
+                    and plan.fires("mass.nonfinite") is not None:
+                # corrupt the merged telemetry deterministically: the monitor
+                # feed's NaN clamp must neutralise it before the reuse
+                # collector / tuner see it (the defense this fault exercises)
+                merged[::3] = np.nan
+                merged[1::5] = np.inf
+            payload: Optional[Dict] = None
+            if sync:
+                self.monitor.on_macro_step(merged, n_active=n_active,
+                                           n_tokens=dt, fetched=fl["fetched"],
+                                           degraded=fl["degraded"])
+            else:
+                # boundary snapshots for the worker's plan (apply_plan
+                # revalidates against whatever moves before actuation).  The
+                # free-slot budget is clamped to the squeezed capacity so a
+                # worker-planned bring never overfills the effective pool.
+                pools = self.monitor.pools
+                n_free = int((pools.page_of_slot < 0).sum())
+                n_free = min(n_free, max(0, pools.effective_hbm
+                                         - pools.hbm_occupied))
+                payload = dict(global_mass=merged, n_active=n_active,
+                               n_tokens=dt, fetched=fl["fetched"],
+                               degraded=fl["degraded"],
+                               resident=pools.slot_of >= 0,
+                               n_free=n_free,
+                               active=pools.allocated_mask,
+                               planes=int(getattr(pools, "move_planes", 2)))
 
-        self.pos = st["pos"]
-        self.tok = st["last_tok"]
-        emitted: List[Tuple[int, int]] = []
-        # resolve lazily-admitted rows' deferred first tokens: the
-        # sample fed this macro's scan, so the download is a no-wait
-        # read; it precedes the row's macro tokens in the stream
-        for row, req in rows:
-            if req._first_tok is not None:
-                tk = int(req._first_tok[0])
-                req._first_tok = None
-                req.tokens.append(tk)
-                emitted.append((req.rid, tk))
-        for t in range(toks_np.shape[0]):
+        with _obs.RECORDER.span("serve.emit"):
+            self.pos = st["pos"]
+            self.tok = st["last_tok"]
+            emitted: List[Tuple[int, int]] = []
+            # resolve lazily-admitted rows' deferred first tokens: the
+            # sample fed this macro's scan, so the download is a no-wait
+            # read; it precedes the row's macro tokens in the stream
             for row, req in rows:
-                tk = int(toks_np[t, row])
-                if tk >= 0:
+                if req._first_tok is not None:
+                    tk = int(req._first_tok[0])
+                    req._first_tok = None
                     req.tokens.append(tk)
                     emitted.append((req.rid, tk))
-        for row, req in rows:
-            req._key = st["keys"][row]
-            req._i = int(iters_out[row])
-            if stopped[row]:
-                self._retire(req)
+            for t in range(toks_np.shape[0]):
+                for row, req in rows:
+                    tk = int(toks_np[t, row])
+                    if tk >= 0:
+                        req.tokens.append(tk)
+                        emitted.append((req.rid, tk))
+            for row, req in rows:
+                req._key = st["keys"][row]
+                req._i = int(iters_out[row])
+                if stopped[row]:
+                    self._retire(req)
         if (r := _obs.RECORDER).enabled:
             r.emit("serve.macro", step=self.step_idx, n_steps=int(n_steps),
                    tokens=len(emitted), active=n_active,
@@ -1577,39 +1602,31 @@ class ContinuousBatcher:
         placement), chunked-admission progress next, the prefetch last
         (it re-fetches anything the earlier stages evicted), then the
         table staging."""
-        track = (r := _obs.RECORDER).enabled
         if self._decision_gen is not None:
             gen, self._decision_gen = self._decision_gen, None
-            t0 = time.monotonic()
-            try:
-                (period, plan), waited = self._decision_worker.wait(
-                    gen, timeout=self.watchdog_s)
-            except TimeoutError:       # hung worker: watchdog recovery
-                period, plan = self._worker_recover("hang")
-                waited = time.monotonic() - t0
-            except Exception:          # crashed worker
-                if self.watchdog_s is None:
-                    raise              # no watchdog: fail loud (close()
+            with _obs.RECORDER.span("serve.decision_wait"):
+                t0 = time.monotonic()
+                try:
+                    (period, plan), waited = self._decision_worker.wait(
+                        gen, timeout=self.watchdog_s)
+                except TimeoutError:   # hung worker: watchdog recovery
+                    period, plan = self._worker_recover("hang")
+                    waited = time.monotonic() - t0
+                except Exception:      # crashed worker
+                    if self.watchdog_s is None:
+                        raise          # no watchdog: fail loud (close()
                                        # still tears down cleanly)
-                period, plan = self._worker_recover("crash")
-                waited = time.monotonic() - t0
-            self.monitor.apply_decision(plan)
-            if track:
+                    period, plan = self._worker_recover("crash")
+                    waited = time.monotonic() - t0
+                self.monitor.apply_decision(plan)
+            if (r := _obs.RECORDER).enabled:
                 r.emit("serve.pipeline.decision", step=self.step_idx,
                        generation=gen, period=int(period),
                        bring=0 if plan is None else int(len(plan[0])),
                        evict=0 if plan is None else int(len(plan[1])),
                        wait_ms=waited * 1e3)
-                r.emit("serve.pipeline.stage", step=self.step_idx,
-                       stage="decision_wait",
-                       wall_ms=(time.monotonic() - t0) * 1e3)
         if any(p.chunked and not p.ready for p in self._pending_admits):
-            t0 = time.monotonic()
             self._admit_chunks()
-            if track:
-                r.emit("serve.pipeline.stage", step=self.step_idx,
-                       stage="admit",
-                       wall_ms=(time.monotonic() - t0) * 1e3)
         fl = self._inflight
         if fl is None:
             return
@@ -1619,25 +1636,15 @@ class ContinuousBatcher:
         # guarantee -- the next launch's ensure_resident still backstops
         # (and if the pending decision changes the period, it picks up
         # the difference there, charged as launch-time fetches).
-        t0 = time.monotonic()
         n_next = fl["n_steps"]
         per_row = {row: min(fl["horizons"][row] + n_next,
                             req.max_new_tokens - len(req.tokens))
                    for row, req in fl["rows"]}
         self._prefetched_next += self.monitor.pools.ensure_resident(
             self._need(fl["pos_np"], 0, per_row=per_row))
-        if track:
-            r.emit("serve.pipeline.stage", step=self.step_idx,
-                   stage="prefetch",
-                   wall_ms=(time.monotonic() - t0) * 1e3)
         # stage the next boundary's tables: if nothing above re-slotted a
         # page, the next launch's _tables_for is a pure buffer swap
-        t0 = time.monotonic()
         self._tables_for([row for row, _ in fl["rows"]])
-        if track:
-            r.emit("serve.pipeline.stage", step=self.step_idx,
-                   stage="tables",
-                   wall_ms=(time.monotonic() - t0) * 1e3)
 
     def _admit_reserve(self) -> None:
         """Pop admittable requests into the pending set: rows and pages
@@ -1645,32 +1652,33 @@ class ContinuousBatcher:
         as ``_admit``), but the prefill runs inside overlap windows and
         the row only activates at a macro boundary."""
         pools = self.monitor.pools
-        self._expire_queue()
-        while self.queue and self.rows_free:
-            req = self.queue[0]
-            n_exact = self._pages_exact(req)
-            n_alloc = self._pages_alloc(req)
-            if self._hbm_need + n_exact > pools.effective_hbm:
-                break              # head-of-line: keep arrival order
-            gids = pools.alloc(n_alloc, req.rid)
-            if gids is None:       # head-of-line: keep arrival order
-                break
-            self.queue.popleft()
-            row = self.rows_free.pop()
-            req.row, req.gids, req.n_pages = row, gids, n_exact
-            req.n_alloc = n_alloc
-            self._hbm_need += n_exact
-            self._map_row(req)
-            plen = len(req.prompt)
-            # chunking needs prefill_chunk's contract: batched-prefill
-            # arch, no shared prefix (chunk-local positions must be
-            # absolute), no extra embeds (prefill_chunk takes none)
-            chunked = (self._chunk_width is not None
-                       and self._batched_prefill and self.prefix == 0
-                       and self._ex is None and plen > self._chunk_width)
-            self._pending_admits.append(_PendingAdmit(
-                req=req, plen=plen, chunked=chunked,
-                t_submit=time.monotonic()))
+        with _obs.RECORDER.span("serve.admit"):
+            self._expire_queue()
+            while self.queue and self.rows_free:
+                req = self.queue[0]
+                n_exact = self._pages_exact(req)
+                n_alloc = self._pages_alloc(req)
+                if self._hbm_need + n_exact > pools.effective_hbm:
+                    break          # head-of-line: keep arrival order
+                gids = pools.alloc(n_alloc, req.rid)
+                if gids is None:   # head-of-line: keep arrival order
+                    break
+                self.queue.popleft()
+                row = self.rows_free.pop()
+                req.row, req.gids, req.n_pages = row, gids, n_exact
+                req.n_alloc = n_alloc
+                self._hbm_need += n_exact
+                self._map_row(req)
+                plen = len(req.prompt)
+                # chunking needs prefill_chunk's contract: batched-prefill
+                # arch, no shared prefix (chunk-local positions must be
+                # absolute), no extra embeds (prefill_chunk takes none)
+                chunked = (self._chunk_width is not None
+                           and self._batched_prefill and self.prefix == 0
+                           and self._ex is None and plen > self._chunk_width)
+                self._pending_admits.append(_PendingAdmit(
+                    req=req, plen=plen, chunked=chunked,
+                    t_reserved=time.monotonic()))
         if (r := _obs.RECORDER).enabled:
             r.gauge("serve.queue_depth", len(self.queue))
 
@@ -1704,9 +1712,10 @@ class ContinuousBatcher:
         SLO knob: it caps how much prefill compute any single window
         puts in front of the next boundary, trading admission latency
         for boundary stall."""
-        for p in self._pending_admits:
-            if p.chunked and not p.ready:
-                self._dispatch_chunk(p)
+        with _obs.RECORDER.span("serve.admit_chunks"):
+            for p in self._pending_admits:
+                if p.chunked and not p.ready:
+                    self._dispatch_chunk(p)
 
     def _dispatch_packed_prefill(self, pending: List[_PendingAdmit]
                                  ) -> None:
@@ -1714,25 +1723,11 @@ class ContinuousBatcher:
         admissions -- the same pow2-bucketed pass as ``_prefill``, minus
         the sampling sync (the lazy logits ride in the pending record
         until the boundary)."""
-        plens = [p.plen for p in pending]
-        smax = bucket_pages(max(plens))
-        jp = bucket_pages(len(pending))
-        toks = np.zeros((jp, smax), np.int32)
-        plens_p = np.ones((jp,), np.int32)
-        for i, p in enumerate(pending):
-            toks[i, : plens[i]] = p.req.prompt
-            plens_p[i] = self.prefix + plens[i]
-        kw = {}
-        if self._cond is not None:
-            kw["cond"] = jnp.broadcast_to(
-                self._cond, (jp,) + self._cond.shape[1:])
-        if self._ex is not None:
-            kw["extra_embeds"] = jnp.broadcast_to(
-                self._ex, (jp,) + self._ex.shape[1:])
-        logits_b, cache_b = self._prefill_fn(
-            jnp.asarray(toks), jnp.asarray(plens_p), **kw)
+        logits_b, cache_b = self._launch_packed_prefill(
+            [p.req.prompt for p in pending])
         self._write_prefill_pages_batched(cache_b,
-                                          [p.req for p in pending], plens)
+                                          [p.req for p in pending],
+                                          [p.plen for p in pending])
         for i, p in enumerate(pending):
             p.logits = logits_b[i: i + 1]
             p.ready = True
@@ -1819,7 +1814,6 @@ class ContinuousBatcher:
             return []
         self._pending_admits = [p for p in self._pending_admits
                                 if not p.ready]
-        t0 = time.monotonic()
         emitted: List[Tuple[int, int]] = []
         for p in ready:
             req = p.req
@@ -1846,12 +1840,15 @@ class ContinuousBatcher:
             r.emit("serve.admit", step=self.step_idx, joiners=len(ready),
                    pages=int(sum(p.req.n_alloc for p in ready)),
                    queue_depth=len(self.queue),
-                   wall_ms=(now - t0) * 1e3,
+                   rids=[p.req.rid for p in ready],
+                   # queue wait: submit to the reservation
+                   wait_ms=[(p.t_reserved - p.req._t_submit) * 1e3
+                            for p in ready],
                    # the batch's WORST reservation-to-activation stall:
                    # the admission-latency price of deferring the sample
                    # sync to a boundary (what admit_chunk_tokens trades
                    # boundary stall against)
-                   stall_ms=(now - min(p.t_submit for p in ready)) * 1e3)
+                   stall_ms=(now - min(p.t_reserved for p in ready)) * 1e3)
             r.count("serve.admitted", len(ready))
             r.gauge("serve.queue_depth", len(self.queue))
         return emitted
@@ -2111,7 +2108,7 @@ class TrafficScheduler:
             self.active.append(_SynthActive(spec, gids, pattern))
         if joiners and (r := _obs.RECORDER).enabled:
             r.emit("serve.admit", step=self.now, joiners=joiners,
-                   pages=pages, queue_depth=len(self.pending), wall_ms=0.0)
+                   pages=pages, queue_depth=len(self.pending))
             r.count("serve.admitted", joiners)
             r.gauge("serve.queue_depth", len(self.pending))
 

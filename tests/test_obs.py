@@ -8,8 +8,10 @@ change, HOLD escalation) reconstructed *from the event log alone*, the
 JSONL round-trip and Perfetto structural validity, the report CLI, the
 StepTimer straggler path, and behavioral identity of the traffic
 scheduler with telemetry on vs off."""
+import contextlib
 import json
 import math
+import threading
 
 import numpy as np
 import pytest
@@ -76,12 +78,90 @@ def test_events_filter_by_type_and_prefix():
     r = obs.Recorder(enabled=True)
     r.emit("serve.retire", step=0, rid=0, tokens=1)
     r.emit("serve.admit", step=0, joiners=1, pages=2, queue_depth=0,
-           wall_ms=0.1)
+           rids=[0], wait_ms=[0.1])
     r.emit("tier.move", manager="m0", step=4, period=4, promoted=1,
            evicted=0, pages_moved=2, cost=1.0)
     assert len(r.events("serve.admit")) == 1
     assert len(r.events(prefix="serve.")) == 2
     assert len(r.events(prefix="tier.")) == 1
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: counts what opens."""
+
+    def __init__(self):
+        self.opened = []
+
+    def __call__(self, name):
+        self.opened.append(name)
+        return contextlib.nullcontext()
+
+
+@pytest.fixture()
+def annotations(monkeypatch):
+    import jax.profiler
+    fake = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", fake)
+    return fake
+
+
+def test_disabled_span_is_one_shared_noop(annotations):
+    r = obs.Recorder(enabled=False)
+    a, b = r.span("serve.step"), r.span("serve.admit", rids=[1])
+    assert a is b, "a disabled recorder hands out one shared no-op context"
+    with a, b:
+        pass
+    assert r.events() == [] and annotations.opened == []
+
+
+def test_span_emits_duration_and_parent_per_thread(annotations):
+    r = obs.Recorder(enabled=True)
+    inner_started, outer_done = threading.Event(), threading.Event()
+
+    def other_thread():
+        with r.span("tuner.on_step"):
+            inner_started.set()
+            outer_done.wait(5.0)
+
+    with r.span("serve.step"):
+        with r.span("serve.prefill", joiners=2):
+            with r.span("serve.prefill.launch"):
+                pass
+        th = threading.Thread(target=other_thread)
+        th.start()
+        assert inner_started.wait(5.0)
+        with r.span("serve.emit"):
+            pass
+        outer_done.set()
+        th.join(5.0)
+    assert not th.is_alive()
+    got = [(e["name"], e["parent"]) for e in r.events("obs.span")]
+    # spans land as they close; another thread's span has its own stack
+    assert got[:2] == [("serve.prefill.launch", "serve.prefill"),
+                       ("serve.prefill", "serve.step")]
+    assert ("tuner.on_step", "") in got
+    assert ("serve.emit", "serve.step") in got
+    assert got[-1] == ("serve.step", "")
+    ev = r.events("obs.span")
+    assert ev[1]["joiners"] == 2
+    assert all(e["ms"] >= 0.0 for e in ev)
+    children = [e["ms"] for e in ev if e["parent"] == "serve.step"]
+    assert ev[-1]["ms"] >= max(children)
+    assert sorted(annotations.opened) == sorted(n for n, _ in got)
+
+
+def test_span_is_registered_and_the_taxonomy_check_passes():
+    import importlib.util
+    import pathlib
+    assert obs.EVENTS["obs.span"].fields == ("name", "parent", "ms")
+    assert "serve.pipeline.stage" not in obs.EVENTS
+    assert "wall_ms" not in obs.EVENTS["serve.admit"].fields
+    root = pathlib.Path(__file__).resolve().parents[1]
+    spec = importlib.util.spec_from_file_location(
+        "_check_events", root / "scripts" / "check_events.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    assert mod.check(root) == 0
 
 
 def test_install_swaps_recorder_for_module_attribute_readers(rec):

@@ -243,7 +243,8 @@ def test_pipelined_token_parity_with_synchronous():
 def test_pipelined_table_upload_cache():
     """The epoch-keyed table cache: boundaries where tiering moved no
     page and no row changed skip the rebuild+upload (counted), and the
-    pipelined run emits its closed stage/decision event taxonomy."""
+    pipelined run times its overlap stages as spans beside its
+    decision/chunk events."""
     import jax
     import repro.configs as C
     from repro.models import model as mdl
@@ -271,9 +272,12 @@ def test_pipelined_table_upload_cache():
         assert counters.get("pool.table_upload.skipped", 0) >= 1, \
             "quiet boundaries must reuse the staged upload"
         types = {e["type"] for e in rec.events()}
-        assert {"serve.pipeline.stage", "serve.pipeline.decision",
+        assert {"serve.pipeline.decision",
                 "serve.pipeline.admit_chunk"} <= types
-        stages = {e["stage"] for e in rec.events("serve.pipeline.stage")}
-        assert stages == {"decision_wait", "prefetch", "tables", "admit"}
+        # the overlap window's stages are spans under the step
+        stages = {e["name"] for e in rec.events("obs.span")
+                  if e["parent"] == "serve.step"}
+        assert {"serve.decision_wait", "serve.admit_chunks",
+                "pool.ensure_resident", "serve.tables"} <= stages
     finally:
         _obs.install(_obs.Recorder())

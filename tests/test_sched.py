@@ -524,6 +524,58 @@ def test_admission_prefills_in_one_packed_pass(monkeypatch):
         "three same-step joiners must share one packed prefill"
 
 
+def test_sync_step_spans_name_each_part_of_the_boundary():
+    """One synchronous macro step with a joiner opens the served path's
+    spans in order, each under its parent, and ``serve.admit`` names the
+    joiner with its queue wait."""
+    import jax
+    import repro.configs as C
+    from repro.models import model as mdl
+    from repro.obs import telemetry as _obs
+    from repro.serve import sched as S
+
+    cfg = C.reduced("gemma3-12b")
+    params, _ = mdl.init(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(3)
+    prev = _obs.get()
+    rec = _obs.install(_obs.Recorder(enabled=True))
+    try:
+        b = S.ContinuousBatcher(params, cfg, max_active=2, max_len=32,
+                                page_size=4,
+                                monitor=_tiny_serving_stack(cfg, params))
+        b.submit(S.Request(rid=7, max_new_tokens=4, prompt=rng.integers(
+            0, cfg.vocab_size, size=6).astype(np.int32)))
+        b.step()
+    finally:
+        _obs.install(prev)
+    spans = [(e["name"], e["parent"]) for e in rec.events("obs.span")]
+    # spans land as they close: children before their parent
+    assert spans == [
+        ("serve.rebalance", "serve.step"),
+        ("serve.admit", "serve.step"),
+        ("serve.prefill.launch", "serve.prefill"),
+        ("pool.write_prefill", "serve.prefill"),
+        ("serve.prefill.first_tokens", "serve.prefill"),
+        ("serve.prefill", "serve.step"),
+        ("pool.ensure_resident", "serve.step"),
+        ("serve.tables", "serve.step"),
+        ("serve.macro.launch", "serve.step"),
+        ("serve.macro.wait", "serve.step"),
+        ("tier.account", "serve.monitor"),
+        ("tier.maybe_tier", "serve.monitor"),
+        ("tuner.on_step", "serve.monitor"),
+        ("serve.monitor", "serve.step"),
+        ("serve.emit", "serve.step"),
+        ("serve.step", ""),
+    ]
+    (admit,) = rec.events("serve.admit")
+    assert admit["rids"] == [7] and len(admit["wait_ms"]) == 1
+    step_ms = rec.events("obs.span")[-1]["ms"]
+    assert 0.0 <= admit["wait_ms"][0] < step_ms
+    assert "wall_ms" not in admit
+    assert "serve.step_s" not in rec.hists
+
+
 # ---------------------------------------------------------------------------
 # shape-bucketed allocation (property tests)
 # ---------------------------------------------------------------------------
