@@ -66,11 +66,11 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
                     return_mass: bool = False, impl: Optional[str] = None):
     # Ragged multi-request tables pad short rows with -1; those entries are
     # already masked out by `lengths`, so clamp them to a valid physical
-    # page before the gather (the Pallas index_map would otherwise DMA out
-    # of bounds, and the reference gather would wrap).  Precondition: a -1
-    # *inside* the `lengths` range means a non-resident page (slot_of ==
-    # -1) leaked into the table -- callers must ensure_resident first; the
-    # clamp cannot distinguish that from padding on traced values.
+    # page: that keeps the kernel's page copies in bounds and stops the
+    # reference gather from wrapping.  Precondition: a -1 *inside* the
+    # `lengths` range means a non-resident page (slot_of == -1) leaked into
+    # the table -- callers must ensure_resident first; the clamp cannot
+    # distinguish that from padding on traced values.
     page_table = jnp.maximum(page_table, 0)
     impl = impl or default_impl("interpret")
     if impl == "reference":

@@ -130,10 +130,12 @@ _ALL = [
         "restarts are exhausted and the loop stays synchronous)"),
     _ev("serve.macro",
         ("step", "n_steps", "tokens", "active", "fetched", "wall_ms",
-         "straggler"),
+         "straggler", "kv_pages_live", "kv_pages_table"),
         "one macro-step launch: a movement period of device-resident "
         "decode -- scan length, tokens served, mean active rows, up-front "
-        "prefetch misses, wall time, StepTimer straggler flag"),
+        "prefetch misses, wall time, StepTimer straggler flag, the active "
+        "rows' live KV pages at launch and the page table's size (rows x "
+        "table pages): the paged kernel walks only the live ones"),
     _ev("serve.stream",
         ("phase", "tokens", "wall_ms"),
         "single-stream monitored_generate started/finished"),

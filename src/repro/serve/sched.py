@@ -1477,10 +1477,15 @@ class ContinuousBatcher:
                 if stopped[row]:
                     self._retire(req)
         if (r := _obs.RECORDER).enabled:
+            # the pages the paged kernel walks: each active row's live
+            # pages at launch, against every row's whole table
+            live = sum(-(-(int(fl["pos_np"][row]) + 1) // self.page_size)
+                       for row, _ in rows)
             r.emit("serve.macro", step=self.step_idx, n_steps=int(n_steps),
                    tokens=len(emitted), active=n_active,
                    fetched=int(fl["fetched"]), wall_ms=macro_wall * 1e3,
-                   straggler=straggler)
+                   straggler=straggler, kv_pages_live=live,
+                   kv_pages_table=self.max_active * self.n_row_pages)
             r.count("serve.tokens", len(emitted))
         return emitted, payload
 

@@ -97,56 +97,132 @@ def test_flash_attention_noncausal():
 # ---------------------------------------------------------------------------
 
 
+# Walks over more than one block of pages per row.  At page 64, 2 KV heads
+# and head dim 128 the kernel gathers 8 f32 (16 bf16) pages a grid step,
+# so a 20-page table ends in a partial block; 4 and 1 KV heads give 4 and
+# 16 f32 pages a block.
+WALK_PAGES, WALK_PAGE, WALK_D, WALK_PHYS = 20, 64, 128, 64
+WALKS = {
+    "len0": [0, 20 * 64, 700],            # an idle row next to full ones
+    "len1": [1, 20 * 64, 65],
+    "block-edge": [512, 513, 1024],       # 512 = 8 pages, 1024 = 16 pages
+    "window-skip": [20 * 64, 1000, 700],  # a window of 200 skips blocks
+    "ragged": [700, 20 * 64, 130],        # table -1 past each row's pages
+    # an idle row between live ones: the last live block of row 0 starts
+    # row 2's first block, across the idle row, into the other buffer slot
+    "len0-mid": [700, 0, 20 * 64],
+    "len0-ends": [0, 900, 0],             # the first live block is row 1's
+    # at a window of 200 row 2 attends 800-999, so its first live block is
+    # block 1, and row 0's only live block (1080-1279) must start it
+    "len0-mid-late": [20 * 64, 0, 1000],
+}
+
+
+def _walk_inputs(walk, h, kv, dtype):
+    lengths = WALKS[walk]
+    b = len(lengths)
+    key = jax.random.PRNGKey(len(walk) * 10 + kv)
+    q = jax.random.normal(key, (b, h, WALK_D), dtype)
+    shape = (WALK_PHYS, WALK_PAGE, kv, WALK_D)
+    kp = jax.random.normal(jax.random.fold_in(key, 1), shape, dtype)
+    vp = jax.random.normal(jax.random.fold_in(key, 2), shape, dtype)
+    pt = np.array(jax.random.permutation(jax.random.fold_in(key, 3),
+                                          WALK_PHYS)[:b * WALK_PAGES]
+                   ).reshape(b, WALK_PAGES)
+    if walk == "ragged":
+        for r, n in enumerate(lengths):
+            pt[r, -(-n // WALK_PAGE):] = -1
+    return q, kp, vp, jnp.asarray(pt, jnp.int32), jnp.asarray(lengths,
+                                                              jnp.int32)
+
+
+def _assert_rows_match(got, want, lengths, atol):
+    """Rows that attend something match the oracle; a row of length 0
+    attends nothing and the kernel writes zeros there."""
+    got = np.asarray(got, np.float32)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], np.asarray(want, np.float32)[live],
+                               atol=atol)
+    np.testing.assert_array_equal(got[~live], 0.0)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("h,kv,d,page", [(4, 4, 64, 16), (8, 2, 64, 32),
-                                         (8, 1, 128, 16)])
-def test_paged_attention_matches_ref(h, kv, d, page, dtype):
-    b, n_pages, p_phys = 3, 8, 64
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (b, h, d), dtype)
-    kp = jax.random.normal(jax.random.PRNGKey(1), (p_phys, page, kv, d), dtype)
-    vp = jax.random.normal(jax.random.PRNGKey(2), (p_phys, page, kv, d), dtype)
-    pt = jax.random.permutation(
-        jax.random.PRNGKey(3), p_phys)[: b * n_pages].reshape(b, n_pages)
-    lengths = jnp.array([n_pages * page, n_pages * page - 7, page + 3],
-                        jnp.int32)
+@pytest.mark.parametrize("h,kv,d,page,walk", [
+    pytest.param(4, 4, 64, 16, None, id="4-4-64-16"),
+    pytest.param(8, 2, 64, 32, None, id="8-2-64-32"),
+    pytest.param(8, 1, 128, 16, None, id="8-1-128-16"),
+    *[pytest.param(8, 2, WALK_D, WALK_PAGE, w, id=f"walk-{w}")
+      for w in ("len0", "len1", "block-edge", "ragged", "len0-mid",
+                "len0-ends")]])
+def test_paged_attention_matches_ref(h, kv, d, page, walk, dtype):
+    if walk is None:
+        b, n_pages, p_phys = 3, 8, 64
+        key = jax.random.PRNGKey(0)
+        q = jax.random.normal(key, (b, h, d), dtype)
+        kp = jax.random.normal(jax.random.PRNGKey(1), (p_phys, page, kv, d),
+                               dtype)
+        vp = jax.random.normal(jax.random.PRNGKey(2), (p_phys, page, kv, d),
+                               dtype)
+        pt = jax.random.permutation(
+            jax.random.PRNGKey(3), p_phys)[: b * n_pages].reshape(b, n_pages)
+        lengths = jnp.array([n_pages * page, n_pages * page - 7, page + 3],
+                            jnp.int32)
+    else:
+        q, kp, vp, pt, lengths = _walk_inputs(walk, h, kv, dtype)
     o = ops.paged_attention(q, kp, vp, pt, lengths, impl="interpret")
-    r = ref.paged_attention_ref(q, kp, vp, pt, lengths)
+    r = ops.paged_attention(q, kp, vp, pt, lengths, impl="reference")
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(r, np.float32), atol=tol)
+    _assert_rows_match(o, r, lengths, tol)
 
 
 @pytest.mark.parametrize("h,kv", [(4, 4), (8, 2), (8, 1)])  # GQA ratios
-@pytest.mark.parametrize("window,softcap", [(0, 0.0), (3, 0.0), (0, 5.0),
-                                            (8, 5.0)])
-def test_paged_attention_kernel_mass_matches_oracle(h, kv, window, softcap):
+@pytest.mark.parametrize("window,softcap,walk", [
+    pytest.param(0, 0.0, None, id="0-0.0"),
+    pytest.param(3, 0.0, None, id="3-0.0"),
+    pytest.param(0, 5.0, None, id="0-5.0"),
+    pytest.param(8, 5.0, None, id="8-5.0"),
+    pytest.param(0, 0.0, "len0", id="0-0.0-walk-len0"),
+    pytest.param(0, 5.0, "len1", id="0-5.0-walk-len1"),
+    pytest.param(0, 0.0, "block-edge", id="0-0.0-walk-block-edge"),
+    pytest.param(200, 0.0, "window-skip", id="200-0.0-walk-window-skip"),
+    pytest.param(200, 5.0, "ragged", id="200-5.0-walk-ragged"),
+    pytest.param(0, 0.0, "len0-mid", id="0-0.0-walk-len0-mid"),
+    pytest.param(0, 5.0, "len0-ends", id="0-5.0-walk-len0-ends"),
+    pytest.param(200, 0.0, "len0-mid-late", id="200-0.0-walk-len0-mid-late")])
+def test_paged_attention_kernel_mass_matches_oracle(h, kv, window, softcap,
+                                                    walk):
     """The mass emitted from the kernel's own online-softmax accumulators
     (the fused telemetry output) equals the reference oracle's per-page
     attention-probability mass -- across sliding windows, tanh softcap and
-    every GQA ratio, including ragged -1-padded tables."""
-    b, n_pages, p_phys, page, d = 3, 5, 24, 4, 16
-    key = jax.random.PRNGKey(h * 100 + window)
-    q = jax.random.normal(key, (b, h, d))
-    kp = jax.random.normal(jax.random.fold_in(key, 1), (p_phys, page, kv, d))
-    vp = jax.random.normal(jax.random.fold_in(key, 2), (p_phys, page, kv, d))
-    pt = jnp.asarray([[2, 7, 11, 3, 9],
-                      [5, 1, 20, -1, -1],          # ragged short row
-                      [8, 4, 6, 12, 17]], jnp.int32)
-    lengths = jnp.asarray([n_pages * page - 2, 3 * page - 1, 2 * page + 3],
-                          jnp.int32)
+    every GQA ratio, including ragged -1-padded tables, and over walks of
+    several blocks of pages that skip what a row does not attend."""
+    if walk is None:
+        b, n_pages, p_phys, page, d = 3, 5, 24, 4, 16
+        key = jax.random.PRNGKey(h * 100 + window)
+        q = jax.random.normal(key, (b, h, d))
+        kp = jax.random.normal(jax.random.fold_in(key, 1),
+                               (p_phys, page, kv, d))
+        vp = jax.random.normal(jax.random.fold_in(key, 2),
+                               (p_phys, page, kv, d))
+        pt = jnp.asarray([[2, 7, 11, 3, 9],
+                          [5, 1, 20, -1, -1],          # ragged short row
+                          [8, 4, 6, 12, 17]], jnp.int32)
+        lengths = jnp.asarray([n_pages * page - 2, 3 * page - 1,
+                               2 * page + 3], jnp.int32)
+    else:
+        q, kp, vp, pt, lengths = _walk_inputs(walk, h, kv, jnp.float32)
     out, mass = ops.paged_attention(q, kp, vp, pt, lengths, window=window,
                                     softcap=softcap, return_mass=True,
                                     impl="interpret")
     ref_o, ref_m = ops.paged_attention(q, kp, vp, pt, lengths, window=window,
                                        softcap=softcap, return_mass=True,
                                        impl="reference")
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_o), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(mass), np.asarray(ref_m),
-                               atol=1e-5)
+    _assert_rows_match(out, ref_o, lengths, 1e-5)
+    _assert_rows_match(mass, ref_m, lengths, 1e-5)
     # head-normalised: every in-length row's mass sums to ~1
     np.testing.assert_allclose(np.asarray(mass).sum(axis=1),
-                               np.ones(b), atol=1e-5)
+                               (np.asarray(lengths) > 0).astype(np.float32),
+                               atol=1e-5)
 
 
 @pytest.mark.parametrize("scheduler", ["reactive", "predictive"])

@@ -526,8 +526,9 @@ def test_admission_prefills_in_one_packed_pass(monkeypatch):
 
 def test_sync_step_spans_name_each_part_of_the_boundary():
     """One synchronous macro step with a joiner opens the served path's
-    spans in order, each under its parent, and ``serve.admit`` names the
-    joiner with its queue wait."""
+    spans in order, each under its parent, ``serve.admit`` names the
+    joiner with its queue wait, and ``serve.macro`` counts the live pages
+    of the table."""
     import jax
     import repro.configs as C
     from repro.models import model as mdl
@@ -574,6 +575,10 @@ def test_sync_step_spans_name_each_part_of_the_boundary():
     assert 0.0 <= admit["wait_ms"][0] < step_ms
     assert "wall_ms" not in admit
     assert "serve.step_s" not in rec.hists
+    # the macro counts the pages the kernel walks: the row's 6-token
+    # prompt plus the decoding token fill 2 pages of 4, of a 2 x 8 table
+    (macro,) = rec.events("serve.macro")
+    assert (macro["kv_pages_live"], macro["kv_pages_table"]) == (2, 16)
 
 
 # ---------------------------------------------------------------------------

@@ -68,6 +68,26 @@ def test_paged_attention_compiles_at_qwen3_14b_width(one_chip, q_dtype,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("heads", [10, 8])       # qwen3-14b-tp4, qwen3-8b-tp4
+@pytest.mark.parametrize("window", [0, 1024])
+def test_paged_attention_compiles_at_the_served_shape(one_chip, heads,
+                                                      window):
+    """The shape the benchmark cells serve: 8 rows of 68 table pages of 64
+    positions over an f32 pool of 640 slots, 2 KV heads of 128.  The walk
+    over live blocks of pages stays one kernel: one custom call, no XLA
+    pass over the pool around it."""
+    b, cols, page, slots, kvh, d = 8, 68, 64, 640, 2, 128
+    fn = jax.jit(lambda q, k, v, t, n: ops.paged_attention(
+        q, k, v, t, n, window=window, return_mass=True, impl="pallas"))
+    compiled = fn.lower(
+        _sds((b, heads, d), jnp.float32, one_chip),
+        _sds((slots, page, kvh, d), jnp.float32, one_chip),
+        _sds((slots, page, kvh, d), jnp.float32, one_chip),
+        _sds((b, cols), jnp.int32, one_chip),
+        _sds((b,), jnp.int32, one_chip)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_mla_compiles_at_deepseek_v3_width(one_chip, dtype):
     cfg = C.get("deepseek-v3-671b")
