@@ -3,8 +3,9 @@
 ``paged_attention`` counts one decode query per row against the keys the
 row attends (its length), not the padded page table the kernel walks: a
 kernel that learns to skip padding raises its share honestly.  Model
-FLOPs count 2 per multiply-add of every matrix product a token needs,
-plus attention at the token's actual context.
+FLOPs are counted by the configuration's architecture
+(``bench/arch/<name>.py``), which also holds the cost functions of any
+kernel only that architecture runs.
 """
 from __future__ import annotations
 
@@ -12,8 +13,9 @@ from typing import Dict, Iterable, Tuple
 
 import numpy as np
 
-__all__ = ["paged_attention_cost", "padded_attention_cost",
-           "matmul_params", "model_flops"]
+from bench import arch
+
+__all__ = ["paged_attention_cost", "padded_attention_cost", "model_flops"]
 
 
 def paged_attention_cost(lengths: Iterable[int], *, heads: int,
@@ -39,26 +41,9 @@ def padded_attention_cost(rows: int, table_pages: int, page_size: int,
     return paged_attention_cost([table_pages * page_size] * rows, **kw)
 
 
-def matmul_params(conf: Dict) -> Tuple[int, int]:
-    """(matrix parameters of one layer, of the unembedding)."""
-    d, h, kv = conf["hidden_size"], conf["num_attention_heads"], \
-        conf["num_key_value_heads"]
-    hd, ff = conf["head_dim"], conf["intermediate_size"]
-    per_layer = d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * ff
-    return per_layer, d * conf["vocab_size"]
-
-
 def model_flops(conf: Dict, prompts: Iterable[int],
                 decode_contexts: Iterable[int]) -> float:
-    """FLOPs of prefilling ``prompts`` (lengths; logits at the last
-    position only) and of decoding one token at each of
-    ``decode_contexts`` (keys attended, the new token's included)."""
-    per_layer, unembed = matmul_params(conf)
-    n_layers = conf["num_hidden_layers"]
-    attn = 4.0 * conf["num_attention_heads"] * conf["head_dim"] * n_layers
-    p = np.asarray(list(prompts), np.float64)
-    c = np.asarray(list(decode_contexts), np.float64)
-    flops = 2.0 * per_layer * n_layers * (p.sum() + c.size)
-    flops += 2.0 * unembed * (p.size + c.size)
-    flops += attn * (float((p * (p + 1) / 2).sum()) + float(c.sum()))
-    return float(flops)
+    """FLOPs of prefilling ``prompts`` and of decoding one token at each
+    of ``decode_contexts``, as the configuration's architecture
+    (``bench/arch/<reference>.py``) counts them."""
+    return arch.of(conf).model_flops(conf, prompts, decode_contexts)

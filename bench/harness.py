@@ -6,8 +6,11 @@ a file of its own (``bench/cells/<cell>.json``: the offered rate and the
 limits of the correctness check).  Every metric, end-to-end and
 per-layer, is a reader in ``bench/metrics/<metric>.py``; a mix's arrival
 process and length distributions are modules under ``bench/arrivals/``
-and ``bench/lengths/``.  Everything is found by name: a new cell, mix,
-configuration or metric is new files plus entries.
+and ``bench/lengths/``; a configuration's ``reference`` names its
+architecture (``bench/arch/<name>.py``) and its plain reference
+(``bench/reference/<name>.py``).  Everything is found by name: a new
+cell, mix, configuration, architecture or metric is new files plus
+entries.
 
 The window drives the program's served path as users get it:
 ``ContinuousBatcher.submit`` / ``.step`` in its default synchronous
@@ -30,6 +33,8 @@ import time
 from typing import Dict, List
 
 import numpy as np
+
+from bench import arch
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
@@ -60,6 +65,7 @@ def load(name: str, root: pathlib.Path = ROOT) -> Cell:
     wl = cells[name]
     entry = {c["name"]: c for c in bench["configs"]}[wl["config"]]
     conf = json.loads((root / entry["file"]).read_text())
+    arch.of(conf)                   # an unknown architecture fails here
     mix = json.loads((root / "bench" / "traffic"
                       / f"{wl['traffic']}.json").read_text())
     cell = json.loads((root / "bench" / "cells" / f"{name}.json")
@@ -254,13 +260,11 @@ def setup(cell: Cell, seed: int):
     import jax
     from repro import compile_cache
 
-    from bench import model_adapter
-
     compile_cache.enable()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     clock = CompileClock()
-    cfg = model_adapter.model_config(cell.conf)
-    params = model_adapter.make_params(cell.conf, seed)
+    cfg = arch.model_config(cell.conf)
+    params = arch.make_params(cell.conf, seed)
     jax.block_until_ready(params)
     warm = Server(params, cfg, cell.conf["serving"])
     warm_up(warm, cfg, cell.mix, cell.conf["serving"],
@@ -396,11 +400,19 @@ def window(cell: Cell, cfg, params, *, seed: int, seconds: float,
         late_s=late, events=events,
         failed=sum(1 for q in b.completed
                    if q.status in ("shed", "expired")),
-        pool_itemsize=server.pools.kv_view()["k_hbm"][0].dtype.itemsize,
+        pool_itemsize=_pool_itemsize(server.pools.kv_view()),
         period=int(server.manager.period), slow_steps=slow,
         collections=[(c - t0, d, g) for c, d, g in collections_])
     server.close()
     return out
+
+
+def _pool_itemsize(kv: Dict) -> int:
+    """Bytes of one element of the page pool (one dtype for every leaf),
+    whatever the geometry names its HBM leaves (``k``/``v``,
+    ``ckv``/``krope``, ``state``)."""
+    return next(a.dtype.itemsize for name, leaves in kv.items()
+                if name.endswith("_hbm") for a in leaves if a is not None)
 
 
 def check(cell: Cell, seed: int, finished, control: bool = False) -> Dict:

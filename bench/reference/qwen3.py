@@ -7,7 +7,8 @@ grouped-query attention (query head h reads KV head h // (H / KV)) ->
 output projection -> residual; RMSNorm -> SwiGLU MLP -> residual; final
 RMSNorm and the untied unembedding.  Straight ``jax.numpy``, no cache, no
 kernels, no batching, every product at ``Precision.HIGHEST``.  It
-imports nothing of the program: its weights come from ``bench.weights``.
+imports nothing of the program: its weights come from ``bench.weights``,
+drawn by the leaf table of ``bench/arch/qwen3.py``.
 
 ``precision="fp8"`` is the control: every matrix product takes float8
 (e4m3) operands, each tensor under one scale, as a float8 serving path
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as W
+from bench.arch import qwen3 as Q
 
 __all__ = ["served_logits"]
 
@@ -114,12 +116,12 @@ def _head(x, gw, *, conf_t, precision):
 
 @functools.partial(jax.jit, static_argnames=("conf_t",))
 def _layer_weights(key, l, *, conf_t):
-    return W.layer(key, dict(conf_t), l)
+    return W.layer(key, Q, dict(conf_t), l)
 
 
 @functools.partial(jax.jit, static_argnames=("conf_t",))
 def _global_weights(key, *, conf_t):
-    return W.globals_(key, dict(conf_t))
+    return W.globals_(key, Q, dict(conf_t))
 
 
 def _conf_t(conf: Dict):

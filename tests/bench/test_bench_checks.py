@@ -11,9 +11,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bench import arch
 from bench import correct as K
 from bench import harness
 from bench import weights as W
+from bench.arch import qwen3
 
 SEED = 2 ** 31 + 1234
 
@@ -103,14 +105,13 @@ def test_sample_holds_the_longest_and_enough_tokens():
 
 def test_one_layer_draw_equals_the_stacked_draw():
     from conftest import TINY_CONF
-    from bench import model_adapter
-    params = model_adapter.make_params(TINY_CONF, SEED)
-    lw = W.layer(W.key_of(SEED), TINY_CONF, 1)
+    params = arch.make_params(TINY_CONF, SEED)
+    lw = W.layer(W.key_of(SEED), qwen3, TINY_CONF, 1)
     slot = params["segments"][0][0]
     # the same draws; jit and eager may round the scaling differently
     np.testing.assert_allclose(slot["attn"]["wq"][1], lw["wq"], rtol=1e-6)
     np.testing.assert_allclose(slot["mlp"]["wo"][1], lw["w_down"],
                                rtol=1e-6)
-    g = W.globals_(W.key_of(SEED), TINY_CONF)
+    g = W.globals_(W.key_of(SEED), qwen3, TINY_CONF)
     np.testing.assert_allclose(params["embed"]["tok"] * 8.0, g["embed"],
                                rtol=1e-6)

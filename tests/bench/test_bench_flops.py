@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from bench import flops
+from bench.arch import qwen3
 
 SMALL = {"hidden_size": 8, "num_attention_heads": 2, "num_key_value_heads": 1,
          "head_dim": 4, "intermediate_size": 16, "vocab_size": 10,
@@ -35,17 +36,26 @@ def test_attended_never_exceeds_the_padded_table():
 
 
 def test_matmul_params_of_a_qwen3_layer():
-    per_layer, unembed = flops.matmul_params(SMALL)
+    per_layer, unembed = qwen3.matmul_params(SMALL)
     # q 8x2x4 + k,v 8x1x4 each + o 2x4x8 + gate,up,down 8x16 each
     assert per_layer == 64 + 32 + 32 + 64 + 3 * 128
     assert unembed == 80
 
 
 def test_model_flops_hand_worked():
-    per_layer, unembed = flops.matmul_params(SMALL)
+    per_layer, unembed = qwen3.matmul_params(SMALL)
     # one prompt of 3 tokens (logits at its last position only) and two
     # decode tokens attending 4 and 5 keys
-    got = flops.model_flops(SMALL, [3], [4, 5])
+    got = qwen3.model_flops(SMALL, [3], [4, 5])
     mm = 2 * per_layer * 3 * (3 + 2) + 2 * unembed * (1 + 2)
     attn = 4 * 2 * 4 * 3 * ((1 + 2 + 3) + (4 + 5))
     assert got == pytest.approx(mm + attn)
+
+
+def test_model_flops_is_the_architectures():
+    # found by the configuration's ``reference`` name
+    conf = dict(SMALL, name="small", reference="qwen3")
+    assert flops.model_flops(conf, [3], [4, 5]) \
+        == qwen3.model_flops(SMALL, [3], [4, 5])
+    with pytest.raises(ValueError, match="known: .*qwen3"):
+        flops.model_flops(dict(conf, reference="nosuch"), [3], [4])
