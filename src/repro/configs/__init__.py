@@ -10,7 +10,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List
 
-from repro.models.config import MLAConfig, ModelConfig, MoEConfig
+from repro.models.config import ModelConfig
 
 from repro.configs import (deepseek_v3_671b, gemma3_12b, musicgen_large,
                            nemotron_4_340b, olmoe_1b_7b, paligemma_3b,
@@ -89,11 +89,14 @@ def reduced(name: str) -> ModelConfig:
         max_seq_len=64, remat=False, moe_impl="dense",
     )
     if cfg.moe is not None:
-        kw["moe"] = MoEConfig(num_experts=8, top_k=2, d_expert=32,
-                              num_shared=cfg.moe.num_shared,
-                              d_shared=32 if cfg.moe.d_shared else 0,
-                              capacity_factor=2.0)
+        # the router keeps its rule; groups shrink to 2 experts each
+        kw["moe"] = dataclasses.replace(
+            cfg.moe, num_experts=8, top_k=2, d_expert=32,
+            d_shared=32 if cfg.moe.d_shared else 0, capacity_factor=2.0,
+            n_group=min(cfg.moe.n_group, 4),
+            topk_group=min(cfg.moe.topk_group, 2), held_start=0, held=0)
     if cfg.mla is not None:
-        kw["mla"] = MLAConfig(q_lora_rank=32, kv_lora_rank=16,
-                              qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16)
+        kw["mla"] = dataclasses.replace(
+            cfg.mla, q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=16,
+            qk_rope_dim=8, v_head_dim=16)
     return dataclasses.replace(cfg, **kw)

@@ -16,6 +16,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import gmm as _megablox_gmm
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import page_hist as _ph
@@ -114,3 +115,29 @@ def paged_attention_mla(q_abs, q_rope, ckv_pages, krope_pages, page_table,
     if not return_mass:
         return out
     return out, mass
+
+
+#: the grouped matmul's (m, k, n) tile on the TPU
+GMM_TILE = (128, 512, 1024)
+
+
+@functools.partial(jax.jit, static_argnames=("impl",))
+def expert_gmm(x, w, group_sizes, *, impl: Optional[str] = None):
+    """Grouped matmul: rows of ``x`` [m, k], sorted by group, times their
+    group's ``w`` [G, k, n] -> float32 [m, n].  Group g owns the
+    ``group_sizes[g]`` rows after groups 0..g-1; rows past the groups'
+    sum are left unspecified (callers select them away).  On a TPU the
+    kernel is the megablox ``gmm`` (its op in a trace is ``gmm``): it
+    visits only the row tiles of groups that have rows, so an expert with
+    no rows costs no weight read.  "reference" is ``lax.ragged_dot``."""
+    impl = impl or default_impl("reference")
+    if impl == "reference":
+        return jax.lax.ragged_dot(x, w, group_sizes,
+                                  preferred_element_type=jnp.float32)
+    (m, k), n = x.shape, w.shape[2]
+    tiling = (min(GMM_TILE[0], m), min(GMM_TILE[1], k), min(GMM_TILE[2], n))
+    pad = -m % tiling[0]            # the kernel takes whole row tiles
+    out = _megablox_gmm(jnp.pad(x, ((0, pad), (0, 0))), w, group_sizes,
+                        preferred_element_type=jnp.float32, tiling=tiling,
+                        interpret=(impl == "interpret"))
+    return out[:m]

@@ -332,12 +332,16 @@ def _mla_kernel(page_table, lengths, qa_ref, qr_ref, ckv_ref, kr_ref,
     pos = pi * page + jax.lax.iota(jnp.int32, page)
     valid = pos < length                           # [page]
 
+    # float32 products at full precision (a single bfloat16 pass would
+    # round queries and latents to 8 bits); bfloat16 ones are exact
+    hi = (jax.lax.Precision.HIGHEST if ckv.dtype == jnp.float32
+          and qa.dtype == jnp.float32 else None)
     logits = (jax.lax.dot_general(
         qa, ckv, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32, precision=hi)
         + jax.lax.dot_general(
         qr, kr, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)) * scale   # [H, page]
+        preferred_element_type=jnp.float32, precision=hi)) * scale
     logits = jnp.where(valid[None, :], logits, NEG_INF)
 
     m_prev = m_scr[...]                            # [H, 1]
@@ -348,7 +352,7 @@ def _mla_kernel(page_table, lengths, qa_ref, qr_ref, ckv_ref, kr_ref,
     l_new = corr * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
     ctx = jax.lax.dot_general(
         p.astype(ckv.dtype), ckv, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)        # [H, R]
+        preferred_element_type=jnp.float32, precision=hi)   # [H, R]
     acc_scr[...] = acc_scr[...] * corr + ctx
     page_col = (jax.lax.iota(jnp.int32, n_pages) == pi).astype(jnp.float32)
     p_scr[...] = p_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True) \

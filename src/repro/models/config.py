@@ -21,23 +21,66 @@ rather than the layer count.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
+import numpy as np
+
 __all__ = [
-    "MoEConfig", "MLAConfig", "LayerKind", "ModelConfig", "parse_kind",
+    "MoEConfig", "MLAConfig", "RopeScaling", "LayerKind", "ModelConfig",
+    "parse_kind",
 ]
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int            # the router's width: every expert it scores
     top_k: int
     d_expert: int               # per-expert FFN hidden size
     num_shared: int = 0         # always-on shared experts (DeepSeek)
     d_shared: int = 0           # shared expert hidden size (0 -> d_expert)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # shard_map path only (it drops tokens)
     router_noise: float = 0.0   # jitter during training
     aux_loss_weight: float = 0.01
+    # router: "softmax" (softmax over the logits, top-k renormalised) or
+    # "sigmoid" (DeepSeek-V3's noaux_tc: sigmoid scores, a correction
+    # bias that only selects, group-limited top-k, weights renormalised
+    # and scaled by routed_scaling_factor)
+    scoring_func: str = "softmax"
+    n_group: int = 1            # sigmoid: expert groups ...
+    topk_group: int = 1         # ... of which the best this many are kept
+    routed_scaling_factor: float = 1.0
+    # the contiguous block of experts this layer holds (its expert-parallel
+    # share): experts [held_start, held_start + held); 0 holds all
+    held_start: int = 0
+    held: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """A checkpoint's ``rope_scaling`` block (only ``type`` "yarn"):
+    frequencies blended between interpolated (/ factor) and extrapolated
+    over the correction range that ``beta_fast`` / ``beta_slow`` rotations
+    at ``original_max_position_embeddings`` give, and attention logits
+    scaled by mscale(factor, mscale_all_dim)^2."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    type: str = "yarn"
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor (``yarn_get_mscale``)."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,6 +90,8 @@ class MLAConfig:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+    # the checkpoint's rope_scaling block (its only rope is MLA's)
+    rope_scaling: Optional[RopeScaling] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,7 +152,7 @@ class ModelConfig:
     param_dtype: str = "float32"
     # implementation switches
     attention_impl: str = "reference"        # reference | pallas
-    moe_impl: str = "dense"                  # dense | shard_map
+    moe_impl: str = "dense"                  # dense (grouped) | shard_map
     moe_chunk: int = 0                       # tokens per dispatch chunk (0 = all)
     remat: bool = True
     unroll_layers: bool = False              # python-loop segments (trip-count-
@@ -116,6 +161,25 @@ class ModelConfig:
     supports_long_context: bool = False
 
     # ------------------------------------------------------------------
+    @property
+    def rope_scaling(self) -> Optional[RopeScaling]:
+        """The checkpoint's rope scaling (held by ``mla``, the one rope
+        that takes it; None elsewhere)."""
+        return self.mla.rope_scaling if self.mla is not None else None
+
+    @property
+    def mla_scale(self):
+        """The MLA attention logits' scale, one for prefill, dense decode
+        and the paged kernel: 1/sqrt(qk_nope + qk_rope), times YaRN's
+        mscale(factor, mscale_all_dim)^2 where the rope is scaled."""
+        m = self.mla
+        scale = 1.0 / np.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+        rs = self.rope_scaling
+        if rs is not None and rs.mscale_all_dim:
+            scale = float(scale) * yarn_mscale(rs.factor,
+                                               rs.mscale_all_dim) ** 2
+        return scale
+
     @property
     def num_layers(self) -> int:
         return sum(len(pat) * rep for pat, rep in self.segments)
@@ -167,7 +231,9 @@ class ModelConfig:
             if k.moe:
                 mo = self.moe
                 n += d * mo.num_experts  # router
-                n += mo.num_experts * 3 * d * mo.d_expert
+                if mo.scoring_func == "sigmoid":
+                    n += mo.num_experts  # selection bias
+                n += mo.n_held * 3 * d * mo.d_expert
                 if mo.num_shared:
                     n += mo.num_shared * 3 * d * (mo.d_shared or mo.d_expert)
             elif ff > 0:
@@ -180,7 +246,7 @@ class ModelConfig:
         if self.moe is None:
             return self.param_count()
         mo = self.moe
-        full_moe = mo.num_experts * 3 * self.d_model * mo.d_expert
+        full_moe = mo.n_held * 3 * self.d_model * mo.d_expert
         act_moe = mo.top_k * 3 * self.d_model * mo.d_expert
         n_moe_layers = sum(1 for k in self.layer_kinds() if k.moe)
         return int(self.param_count() - n_moe_layers * (full_moe - act_moe))

@@ -13,13 +13,15 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.config import MLAConfig, ModelConfig
+from repro.models.config import (MLAConfig, ModelConfig, RopeScaling,
+                                 yarn_mscale)
 
 Params = Dict[str, Any]
 
@@ -44,6 +46,54 @@ def split_tree(tree):
     return params, specs
 
 
+#: bfloat16 pieces an activation is split into for a product with
+#: bfloat16 weights: 3 x 8 significand bits hold a float32's 24
+PIECES = 3
+#: activation elements up to which those pieces run as one stacked
+#: product (the weights read once, as decode needs); larger inputs (a
+#: long prefill) run one product per piece, with no stacked copy
+STACK_ELEMS = 1 << 22
+
+
+def pieces(x, dt, n: int = PIECES):
+    """``n`` arrays of dtype ``dt`` whose float32 sum is ``x`` to n x 8
+    significand bits: each is what the ones before it left, rounded."""
+    out, r = [], x.astype(jnp.float32)
+    for _ in range(n):
+        p = r.astype(dt)
+        out.append(p)
+        r = r - p.astype(jnp.float32)
+    return out
+
+
+def mm(x, w, spec: Optional[str] = None):
+    """``x @ w`` (or the einsum ``spec``) with the weights as stored.
+    Weights at least as wide as the activations are cast to them, as
+    every layer always did.  Narrower ones (bfloat16 storage under a
+    float32 residual stream) are never widened (no float32 copy of the
+    weights): the activations are split into bfloat16 ``pieces``, each
+    product is exact and accumulates in float32, so the result is the
+    float32 product -- a single bfloat16 pass (a TPU's default precision)
+    would round every activation to 8 bits, enough to flip a router's
+    near-tie choice of expert."""
+    if w.dtype.itemsize >= x.dtype.itemsize:
+        w = w.astype(x.dtype)
+        return x @ w if spec is None else jnp.einsum(spec, x, w)
+    if spec is None:
+        spec = "...k,kn->...n"
+    parts = pieces(x, w.dtype)
+    if x.size <= STACK_ELEMS:
+        lhs, rest = spec.split(",", 1)
+        z = next(c for c in "zyxwvu" if c not in spec)
+        out = jnp.einsum(f"{z}{lhs},{rest.replace('->', f'->{z}')}",
+                         jnp.stack(parts), w,
+                         preferred_element_type=jnp.float32)
+        return out.sum(axis=0)
+    # smallest first
+    return sum(jnp.einsum(spec, p, w, preferred_element_type=jnp.float32)
+               for p in parts[::-1])
+
+
 # ---------------------------------------------------------------------------
 # norms / rotary
 # ---------------------------------------------------------------------------
@@ -60,13 +110,50 @@ def rms_norm(x, w, eps=1e-6):
     return (x * w).astype(dt)
 
 
-def rope(x, positions, theta: float):
-    """Apply rotary embedding.  x: [..., S, H, D], positions: [..., S]."""
+def rope_inv_freq(d: int, theta: float,
+                  scaling: Optional[RopeScaling] = None):
+    """Inverse frequencies of dimension pairs (i, i + d/2).  With a YaRN
+    ``scaling`` they are ``DeepseekV3YarnRotaryEmbedding``'s, computed as
+    it computes them (float32, on the host): a linear ramp over the
+    correction range [low, high] that beta_fast and beta_slow rotations at
+    the original context give, from extrapolated (theta^(-2i/d)) below it
+    to interpolated (/ factor) above it."""
+    half = d // 2
+    if scaling is None:
+        return theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+
+    def corr_dim(rot):
+        return (d * math.log(scaling.original_max_position_embeddings
+                             / (rot * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(scaling.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    base = float(theta) ** (np.arange(0, d, 2, dtype=np.float32) / d)
+    extra = 1.0 / base
+    inter = 1.0 / (float(scaling.factor) * base)
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0, 1)
+    keep = 1.0 - ramp               # 1: keep the extrapolated frequency
+    return jnp.asarray((inter * (1 - keep) + extra * keep).astype(np.float32))
+
+
+def rope(x, positions, theta: float, scaling: Optional[RopeScaling] = None):
+    """Apply rotary embedding to pairs (i, i + D/2).  x: [..., S, H, D],
+    positions: [..., S].  ``scaling``: a YaRN block (``rope_inv_freq``;
+    cos and sin then carry mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim), which is 1 where the two are equal)."""
     d = x.shape[-1]
     half = d // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freq = rope_inv_freq(d, theta, scaling)
     ang = positions[..., None].astype(jnp.float32) * freq  # [..., S, half]
     sin, cos = jnp.sin(ang), jnp.cos(ang)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            sin, cos = sin * m, cos * m
     sin = sin[..., None, :]  # broadcast over heads
     cos = cos[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
@@ -98,9 +185,8 @@ def mlp_init(key, cfg: ModelConfig, d_ff: Optional[int] = None):
 
 def mlp_apply(p: Params, cfg: ModelConfig, x):
     if cfg.mlp_kind == "swiglu":
-        h = (jax.nn.silu(x @ p["wi_gate"].astype(x.dtype))
-             * (x @ p["wi_up"].astype(x.dtype)))
-        return h @ p["wo"].astype(x.dtype)
+        h = jax.nn.silu(mm(x, p["wi_gate"])) * mm(x, p["wi_up"])
+        return mm(h, p["wo"])
     # NB: weights must be cast to the activation dtype -- bf16 @ f32
     # silently promotes the whole residual stream to f32 (2x activation
     # memory + 2x collective volume; EXPERIMENTS.md SPerf iteration 5).
@@ -149,13 +235,16 @@ def attention_init(key, cfg: ModelConfig, cross: bool = False):
     return split_tree(tree)
 
 
-def _sdpa_chunked(q, k, v, mask, softcap: float, q_chunk: int = 512):
+def _sdpa_chunked(q, k, v, mask, softcap: float, q_chunk: int = 512,
+                  scale=None, precision=None):
     """Softmax attention, chunked over queries.  q: [B,S,H,D], k/v:
-    [B,T,KV,D], mask: [B,S,T] or [S,T] broadcastable bool."""
+    [B,T,KV,D], mask: [B,S,T] or [S,T] broadcastable bool; ``scale``
+    defaults to 1/sqrt(D); ``precision`` of both products."""
     b, s, h, d = q.shape
     t, kvh = k.shape[1], k.shape[2]
     rep = h // kvh
-    scale = 1.0 / np.sqrt(d)
+    if scale is None:
+        scale = 1.0 / np.sqrt(d)
     kr = jnp.repeat(k, rep, axis=2)  # [B,T,H,D]
     vr = jnp.repeat(v, rep, axis=2)
 
@@ -168,12 +257,13 @@ def _sdpa_chunked(q, k, v, mask, softcap: float, q_chunk: int = 512):
     def one_chunk(args):
         qc, mc = args  # [B,C,H,D], [B,C,T]
         logits = jnp.einsum("bchd,bthd->bhct", qc, kr,
-                            preferred_element_type=jnp.float32) * scale
+                            preferred_element_type=jnp.float32,
+                            precision=precision) * scale
         if softcap > 0:
             logits = jnp.tanh(logits / softcap) * softcap
         logits = jnp.where(mc[:, None], logits, -1e30)
         w = jax.nn.softmax(logits, axis=-1).astype(qc.dtype)
-        return jnp.einsum("bhct,bthd->bchd", w, vr)
+        return jnp.einsum("bhct,bthd->bchd", w, vr, precision=precision)
 
     if n_chunks == 1:
         m = jnp.broadcast_to(mask, (b, s, t))
@@ -271,6 +361,28 @@ def mla_init(key, cfg: ModelConfig):
     return split_tree(tree)
 
 
+#: precision of MLA's attention products (float32 queries against float32
+#: latents): a TPU's default single bfloat16 pass would round both to 8
+#: bits, as ``mm`` avoids for the projections
+MLA_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def mla_project(p: Params, cfg: ModelConfig, x, positions):
+    """The projections every MLA path shares: (q_nope [B,S,H,nope], q_rope
+    [B,S,H,rope] roped, c_kv [B,S,kv_lora] normed, k_rope [B,S,rope] roped,
+    one key shared by every head).  Rope pairs dimensions (i, i + rope/2);
+    a checkpoint that pairs (2i, 2i+1) permutes its rope columns."""
+    m: MLAConfig = cfg.mla
+    cq = rms_norm(mm(x, p["w_dq"]), p["q_norm"])
+    q = mm(cq, p["w_uq"], "bsr,rhk->bshk")
+    q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
+    q_rope = rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
+    c_kv = rms_norm(mm(x, p["w_dkv"]), p["kv_norm"])
+    k_rope = rope(mm(x, p["w_kr"])[:, :, None, :], positions,
+                  cfg.rope_theta, cfg.rope_scaling)[:, :, 0, :]
+    return q_nope, q_rope, c_kv, k_rope
+
+
 def mla_apply(p: Params, cfg: ModelConfig, x, positions, mask, *, past=None):
     """Training/prefill MLA: materialise per-head K/V.  Returns
     (out, (c_kv, k_rope)) -- the *compressed* cache entries.
@@ -280,67 +392,52 @@ def mla_apply(p: Params, cfg: ModelConfig, x, positions, mask, *, past=None):
     over ``past ++ own`` (``mask``: [.., S, P+S]) and still returns only
     its OWN chunk's cache entries."""
     m: MLAConfig = cfg.mla
-    cq = rms_norm(x @ p["w_dq"].astype(x.dtype), p["q_norm"])
-    q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"].astype(x.dtype))
-    q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
-
-    c_kv = rms_norm(x @ p["w_dkv"].astype(x.dtype), p["kv_norm"])
-    k_rope = rope((x @ p["w_kr"].astype(x.dtype))[:, :, None, :], positions,
-                  cfg.rope_theta)  # [B,S,1,rope] shared across heads
-    c_all, kr_all = c_kv, k_rope[:, :, 0, :]
+    q_nope, q_rope, c_kv, k_rope = mla_project(p, cfg, x, positions)
+    c_all, kr_all = c_kv, k_rope
     if past is not None:
         past_ckv, past_krope = past
         c_all = jnp.concatenate([past_ckv.astype(c_kv.dtype), c_kv], axis=1)
         kr_all = jnp.concatenate([past_krope.astype(c_kv.dtype), kr_all],
                                  axis=1)
-    k_nope = jnp.einsum("bsr,rhk->bshk", c_all, p["w_uk"].astype(x.dtype))
-    v = jnp.einsum("bsr,rhk->bshk", c_all, p["w_uv"].astype(x.dtype))
+    k_nope = mm(c_all, p["w_uk"], "bsr,rhk->bshk")
+    v = mm(c_all, p["w_uv"], "bsr,rhk->bshk")
 
     k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(kr_all[:, :, None, :],
+        [k_nope, jnp.broadcast_to(kr_all[:, :, None, :].astype(k_nope.dtype),
                                   k_nope.shape[:3] + (m.qk_rope_dim,))],
         axis=-1)
-    q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-    out = _sdpa_chunked(q_full, k, v, mask, cfg.softcap)
-    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-    return out, (c_kv, k_rope[:, :, 0, :])
+    q_full = jnp.concatenate([q_nope, q_rope.astype(q_nope.dtype)], axis=-1)
+    out = _sdpa_chunked(q_full, k, v, mask, cfg.softcap, scale=cfg.mla_scale,
+                        precision=MLA_PRECISION)
+    out = mm(out, p["wo"], "bshk,hkd->bsd")
+    return out, (c_kv, k_rope)
 
 
 def mla_decode(p: Params, cfg: ModelConfig, x, cache_ckv, cache_krope,
                cache_pos, cur_pos):
     """Absorbed-matrix MLA decode over the compressed cache.
     cache_ckv: [B,T,kv_lora]; cache_krope: [B,T,rope]."""
-    m: MLAConfig = cfg.mla
-    b = x.shape[0]
-    cq = rms_norm(x @ p["w_dq"].astype(x.dtype), p["q_norm"])
-    q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"].astype(x.dtype))
-    q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = rope(q_rope, cur_pos[:, None], cfg.rope_theta)
-
-    c_new = rms_norm(x @ p["w_dkv"].astype(x.dtype), p["kv_norm"])  # [B,1,r]
-    kr_new = rope((x @ p["w_kr"].astype(x.dtype))[:, :, None, :],
-                  cur_pos[:, None], cfg.rope_theta)[:, :, 0, :]
-
-    ckv = jnp.concatenate([cache_ckv, c_new], axis=1).astype(x.dtype)
-    krope = jnp.concatenate([cache_krope, kr_new], axis=1).astype(x.dtype)
+    q_nope, q_rope, c_new, kr_new = mla_project(p, cfg, x, cur_pos[:, None])
+    ckv = jnp.concatenate([cache_ckv, c_new], axis=1).astype(c_new.dtype)
+    krope = jnp.concatenate([cache_krope, kr_new], axis=1).astype(c_new.dtype)
     pos_all = jnp.concatenate([cache_pos, cur_pos[:, None]], axis=1)
 
     # absorb W_uk into q:  q_abs[b,h,r] = sum_k q_nope[b,h,k] W_uk[r,h,k]
-    q_abs = jnp.einsum("bshk,rhk->bshr", q_nope, p["w_uk"].astype(x.dtype))
-    logits = (jnp.einsum("bshr,btr->bhst", q_abs, ckv)
-              + jnp.einsum("bshk,btk->bhst", q_rope, krope))
+    q_abs = mm(q_nope, p["w_uk"], "bshk,rhk->bshr")
+    logits = (jnp.einsum("bshr,btr->bhst", q_abs, ckv,
+                         precision=MLA_PRECISION)
+              + jnp.einsum("bshk,btk->bhst", q_rope.astype(krope.dtype),
+                           krope, precision=MLA_PRECISION))
     # multiply by the precomputed scale (not divide by sqrt) so the paged
     # MLA kernel, which takes `scale` as a static operand, stays
     # bit-identical to this dense path
-    scale = 1.0 / np.sqrt(m.qk_nope_dim + m.qk_rope_dim)
-    logits = logits.astype(jnp.float32) * scale
+    logits = logits.astype(jnp.float32) * cfg.mla_scale
     mask = (pos_all <= cur_pos[:, None]) & (pos_all >= 0)
     logits = jnp.where(mask[:, None, None, :], logits, -1e30)
-    w = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-    ctx = jnp.einsum("bhst,btr->bshr", w, ckv)
-    out = jnp.einsum("bshr,rhk->bshk", ctx, p["w_uv"].astype(x.dtype))
-    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    w = jax.nn.softmax(logits, axis=-1).astype(ckv.dtype)
+    ctx = jnp.einsum("bhst,btr->bshr", w, ckv, precision=MLA_PRECISION)
+    out = mm(ctx, p["w_uv"], "bshr,rhk->bshk")
+    out = mm(out, p["wo"], "bshk,hkd->bsd")
     return out, c_new, kr_new
 
 
@@ -381,5 +478,4 @@ RESID_WEAK_SCALE = False
 
 
 def unembed(p: Params, cfg: ModelConfig, x):
-    w = (p["tok"].T if cfg.tie_embeddings else p["unembed"]).astype(x.dtype)
-    return x @ w
+    return mm(x, p["tok"].T if cfg.tie_embeddings else p["unembed"])

@@ -112,13 +112,15 @@ def init(key, cfg: ModelConfig) -> Tuple[Params, Params]:
 
 def _apply_block_seq(slot_p, cfg: ModelConfig, kind: LayerKind, x, *,
                      positions, cond, mesh, state=None, past=None,
-                     k_positions=None, shard=_IDENT):
+                     k_positions=None, tok_valid=None, shard=_IDENT):
     """Sequence-mode block (train/prefill).  Returns (x, cache_entry, aux).
 
     ``past`` / ``k_positions`` serve chunked prefill: ``past`` is the
     slot's accumulated cache entries from previous chunks (one repeat's
     slice) and ``k_positions`` the concatenated past++own key positions
-    the causal mask must range over."""
+    the causal mask must range over.  ``tok_valid`` [B,S] marks the
+    tokens an expert layer routes (None: all); an attention slot's entry
+    then carries the layer's expert counts under ``moe``."""
     aux = jnp.float32(0.0)
     h = L.rms_norm(x, slot_p["norm1"])
     cache_entry = None
@@ -156,7 +158,10 @@ def _apply_block_seq(slot_p, cfg: ModelConfig, kind: LayerKind, x, *,
         x = x + out
     if kind.moe:
         h2 = L.rms_norm(x, slot_p["norm2"])
-        out, aux = M.moe_apply(slot_p["moe"], cfg, h2, mesh)
+        out, aux, counts = M.moe_apply(slot_p["moe"], cfg, h2, mesh,
+                                       valid=tok_valid)
+        if kind.is_attention:
+            cache_entry["moe"] = counts
         x = x + out
     elif cfg.d_ff > 0 and "mlp" in slot_p:
         h2 = L.rms_norm(x, slot_p["norm2"])
@@ -209,7 +214,7 @@ def _apply_block_decode(slot_p, cfg: ModelConfig, kind: LayerKind, x, cache,
         x = x + out
     if kind.moe:
         h2 = L.rms_norm(x, slot_p["norm2"])
-        out, _ = M.moe_apply(slot_p["moe"], cfg, h2, mesh)
+        out, _, _ = M.moe_apply(slot_p["moe"], cfg, h2, mesh)
         x = x + out
     elif cfg.d_ff > 0 and "mlp" in slot_p:
         h2 = L.rms_norm(x, slot_p["norm2"])
@@ -262,7 +267,7 @@ def _constrain_slots(slot_ps, slot_specs, pshard):
 
 def _run_segments_seq(params, cfg: ModelConfig, x, *, positions, cond, mesh,
                       states=None, pasts=None, k_positions=None,
-                      shard=_IDENT, collect_cache=False,
+                      tok_valid=None, shard=_IDENT, collect_cache=False,
                       param_specs=None, pshard=None):
     """Run all segments in sequence mode.  states (optional) mirror the
     segment/slot structure with [R, ...] stacked leaves (recurrent only);
@@ -292,7 +297,8 @@ def _run_segments_seq(params, cfg: ModelConfig, x, *, positions, cond, mesh,
                     xx, entry, a = _apply_block_seq(
                         slot_ps[j], cfg, kind, xx, positions=positions,
                         cond=cond, mesh=mesh, state=st, past=pst,
-                        k_positions=k_positions, shard=shard)
+                        k_positions=k_positions, tok_valid=tok_valid,
+                        shard=shard)
                     entries.append(entry)
                     aux = aux + a
                 return xx, aux, entries
@@ -330,7 +336,7 @@ def _run_segments_seq(params, cfg: ModelConfig, x, *, positions, cond, mesh,
                 xx, entry, a = _apply_block_seq(
                     slot_ps[j], cfg, kind, xx, positions=positions, cond=cond,
                     mesh=mesh, state=st, past=pst, k_positions=k_positions,
-                    shard=shard)
+                    tok_valid=tok_valid, shard=shard)
                 entries.append(entry)
             return (xx, aux + a), entries
 
@@ -729,15 +735,15 @@ def prefill_batched(params, cfg: ModelConfig, tokens, lengths, *, cond=None,
     x = shard(x, ("batch", "seq", "embed"))
     if cond is not None:
         cond = cond.astype(x.dtype)
+    in_row = jnp.arange(s)[None] < jnp.asarray(lengths)[:, None]
     x, caches, _ = _run_segments_seq(params, cfg, x, positions=positions,
                                      cond=cond, mesh=mesh, shard=shard,
-                                     collect_cache=True)
+                                     tok_valid=in_row, collect_cache=True)
     x = L.rms_norm(x, params["final_norm"])
     last = x[jnp.arange(b), jnp.asarray(lengths) - 1][:, None]
     logits = L.unembed(params["embed"], cfg, last)
 
-    pos_row = jnp.where(jnp.arange(s)[None] < jnp.asarray(lengths)[:, None],
-                        jnp.arange(s)[None], -1).astype(jnp.int32)
+    pos_row = jnp.where(in_row, jnp.arange(s)[None], -1).astype(jnp.int32)
     segs = []
     for si, (pattern, repeats) in enumerate(cfg.segments):
         slots = []
@@ -751,7 +757,18 @@ def prefill_batched(params, cfg: ModelConfig, tokens, lengths, *, cond=None,
             else:
                 slots.append({"k": e["k"], "v": e["v"], "pos": pos})
         segs.append(slots)
-    return logits, {"segments": segs}
+    return logits, _with_moe_counts({"segments": segs}, caches)
+
+
+def _with_moe_counts(cache, caches):
+    """``cache`` plus, where any layer has experts, ``moe`` int32[2]: the
+    pass's token-expert pairs routed to held experts and held experts
+    touched, summed over layers (``moe_apply_grouped``'s counts)."""
+    counts = [e["moe"].sum(axis=0) for seg in caches for e in seg
+              if isinstance(e, dict) and "moe" in e]
+    if counts:
+        cache["moe"] = sum(counts)
+    return cache
 
 
 def prefill_chunk(params, cfg: ModelConfig, tokens, lengths, past=None, *,
@@ -798,6 +815,8 @@ def prefill_chunk(params, cfg: ModelConfig, tokens, lengths, past=None, *,
     x, caches, _ = _run_segments_seq(params, cfg, x, positions=positions,
                                      cond=cond, mesh=mesh, shard=shard,
                                      pasts=past, k_positions=k_positions,
+                                     tok_valid=positions < jnp.asarray(
+                                         lengths)[:, None],
                                      collect_cache=True)
     x = L.rms_norm(x, params["final_norm"])
     take = jnp.clip(jnp.asarray(lengths) - 1 - start, 0, c - 1)
@@ -920,7 +939,7 @@ def decode_step_paged(params, cfg: ModelConfig, kv, tables, gid_tables,
     return _paged_decode_core(params, cfg, kv, tables, gid_tables, tokens,
                               cur_pos, page_size=page_size, impl=impl,
                               cond=cond, state_cols=state_cols, mesh=mesh,
-                              shard=shard)
+                              shard=shard)[:3]
 
 
 def _paged_decode_core(params, cfg: ModelConfig, kv, tables, gid_tables,
@@ -930,7 +949,9 @@ def _paged_decode_core(params, cfg: ModelConfig, kv, tables, gid_tables,
     """The traced body shared by ``decode_step_paged`` (one launch per
     token) and ``decode_macro_step`` (one launch per movement period).
     ``impl=None`` takes the paged kernels' path from the platform: the
-    compiled Pallas kernels on a TPU, the jnp reference on the CPU."""
+    compiled Pallas kernels on a TPU, the jnp reference on the CPU.
+    Returns ``decode_step_paged``'s three results and the expert layers'
+    counts, int32[2] (``moe_apply_grouped``; active rows only)."""
     impl = impl or ops.default_impl("reference")
     b = tokens.shape[0]
     n_row_pages = tables.shape[1]
@@ -968,12 +989,14 @@ def _paged_decode_core(params, cfg: ModelConfig, kv, tables, gid_tables,
     if cond is not None:
         cond = cond.astype(x.dtype)
     mass_sum = jnp.zeros((b, n_row_pages), jnp.float32)
+    moe_sum = jnp.zeros((2,), jnp.int32)
     n_layers = 0
     new_kv = {k_: list(v_) for k_, v_ in kv.items()}
 
     def _block_tail(xx, slot_p, kind):
         """Post-core residual stack shared by every geometry: cross-attn
-        conditioning, MoE / MLP."""
+        conditioning, MoE / MLP.  Returns (xx, expert counts int32[2])."""
+        counts = jnp.zeros((2,), jnp.int32)
         if kind.xattn and cond is not None:
             hx = L.rms_norm(xx, slot_p["norm_x"])
             cpos = jnp.arange(cond.shape[1])[None]
@@ -984,12 +1007,13 @@ def _paged_decode_core(params, cfg: ModelConfig, kv, tables, gid_tables,
             xx = xx + o2
         if kind.moe:
             h2 = L.rms_norm(xx, slot_p["norm2"])
-            o2, _ = M.moe_apply(slot_p["moe"], cfg, h2, mesh)
+            o2, _, counts = M.moe_apply(slot_p["moe"], cfg, h2, mesh,
+                                        valid=active[:, None])
             xx = xx + o2
         elif cfg.d_ff > 0 and "mlp" in slot_p:
             h2 = L.rms_norm(xx, slot_p["norm2"])
             xx = xx + L.mlp_apply(slot_p["mlp"], cfg, h2)
-        return shard(xx, ("batch", "seq", "embed"))
+        return shard(xx, ("batch", "seq", "embed")), counts
 
     def attn_block(xx, slot_p, leaves, kind):
         """Plain/local attention against its (k, v) pool leaves
@@ -1022,41 +1046,32 @@ def _paged_decode_core(params, cfg: ModelConfig, kv, tables, gid_tables,
             softcap=cfg.softcap, return_mass=True, impl=impl)
         out = jnp.einsum("bshk,hkd->bsd", ctx[:, None],
                          slot_p["attn"]["wo"].astype(xx.dtype))
-        xx = _block_tail(xx + out, slot_p, kind)
-        return xx, (kh, vh, khost, vhost, mass)
+        xx, counts = _block_tail(xx + out, slot_p, kind)
+        return xx, (kh, vh, khost, vhost, mass, counts)
 
     def mla_block(xx, slot_p, leaves, kind):
         """Absorbed-matrix MLA against its compressed (ckv, krope) pool
         leaves (per-repeat slices: [hbm_pages|n_logical, page, R|K]) --
         the paged analogue of ``layers.mla_decode``."""
         ckvh, krh, ckvhost, krhost = leaves
-        m = cfg.mla
         p = slot_p["attn"]
         h = L.rms_norm(xx, slot_p["norm1"])
-        cq = L.rms_norm(h @ p["w_dq"].astype(h.dtype), p["q_norm"])
-        q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"].astype(h.dtype))
-        q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
-        q_rope = L.rope(q_rope, cur_pos[:, None], cfg.rope_theta)
-        c_new = L.rms_norm(h @ p["w_dkv"].astype(h.dtype), p["kv_norm"])
-        kr_new = L.rope((h @ p["w_kr"].astype(h.dtype))[:, :, None, :],
-                        cur_pos[:, None], cfg.rope_theta)[:, :, 0, :]
+        q_nope, q_rope, c_new, kr_new = L.mla_project(p, cfg, h,
+                                                      cur_pos[:, None])
         c1 = c_new[:, 0].astype(ckvh.dtype)
         r1 = kr_new[:, 0].astype(krh.dtype)
         ckvh = ckvh.at[wslot, off].set(c1, mode="drop")
         krh = krh.at[wslot, off].set(r1, mode="drop")
         ckvhost = ckvhost.at[wgid, off].set(c1, mode="drop")
         krhost = krhost.at[wgid, off].set(r1, mode="drop")
-        q_abs = jnp.einsum("bshk,rhk->bshr", q_nope,
-                           p["w_uk"].astype(h.dtype))
-        scale = 1.0 / np.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+        q_abs = L.mm(q_nope, p["w_uk"], "bshk,rhk->bshr")
         ctx, mass = ops.paged_attention_mla(
-            q_abs[:, 0], q_rope[:, 0], ckvh, krh, tables, lengths,
-            scale=scale, return_mass=True, impl=impl)
-        out = jnp.einsum("bshr,rhk->bshk", ctx[:, None],
-                         p["w_uv"].astype(xx.dtype))
-        out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(xx.dtype))
-        xx = _block_tail(xx + out, slot_p, kind)
-        return xx, (ckvh, krh, ckvhost, krhost, mass)
+            q_abs[:, 0], q_rope[:, 0].astype(q_abs.dtype), ckvh, krh, tables,
+            lengths, scale=cfg.mla_scale, return_mass=True, impl=impl)
+        out = L.mm(ctx[:, None], p["w_uv"], "bshr,rhk->bshk")
+        out = L.mm(out, p["wo"], "bshk,hkd->bsd")
+        xx, counts = _block_tail(xx + out, slot_p, kind)
+        return xx, (ckvh, krh, ckvhost, krhost, mass, counts)
 
     def state_block(xx, slot_p, leaves, kind):
         """Recurrent cell against its packed state page (per-repeat
@@ -1073,8 +1088,8 @@ def _paged_decode_core(params, cfg: ModelConfig, kv, tables, gid_tables,
         flat = pack_state(new_state).astype(sth.dtype)
         sth = sth.at[swslot].set(flat, mode="drop")
         sthost = sthost.at[swgid].set(flat, mode="drop")
-        xx = _block_tail(xx + out, slot_p, kind)
-        return xx, (sth, sthost, smass)
+        xx, counts = _block_tail(xx + out, slot_p, kind)
+        return xx, (sth, sthost, smass, counts)
 
     def slot_leaves(kind, li):
         if kind.is_attention and kind.mla:
@@ -1088,13 +1103,13 @@ def _paged_decode_core(params, cfg: ModelConfig, kv, tables, gid_tables,
     def store_leaves(kind, li, upd):
         if kind.is_attention and kind.mla:
             (new_kv["ckv_hbm"][li], new_kv["krope_hbm"][li],
-             new_kv["ckv_host"][li], new_kv["krope_host"][li]) = upd[:-1]
+             new_kv["ckv_host"][li], new_kv["krope_host"][li]) = upd[:-2]
         elif kind.is_attention:
             (new_kv["k_hbm"][li], new_kv["v_hbm"][li],
-             new_kv["k_host"][li], new_kv["v_host"][li]) = upd[:-1]
+             new_kv["k_host"][li], new_kv["v_host"][li]) = upd[:-2]
         else:
-            new_kv["state_hbm"][li], new_kv["state_host"][li] = upd[:-1]
-        return upd[-1]
+            new_kv["state_hbm"][li], new_kv["state_host"][li] = upd[:-2]
+        return upd[-2:]
 
     def one_block(xx, slot_p, leaves, kind):
         if kind.is_attention and kind.mla:
@@ -1132,8 +1147,9 @@ def _paged_decode_core(params, cfg: ModelConfig, kv, tables, gid_tables,
         else:
             x, stacked = jax.lax.scan(body, x, (slot_params, seg_leaves))
         for j in range(nslots):
-            mass = store_leaves(kinds[j], li + j, stacked[j])
+            mass, counts = store_leaves(kinds[j], li + j, stacked[j])
             mass_sum = mass_sum + mass.sum(axis=0)
+            moe_sum = moe_sum + counts.sum(axis=0)
             n_layers += repeats
         li += nslots
 
@@ -1141,7 +1157,7 @@ def _paged_decode_core(params, cfg: ModelConfig, kv, tables, gid_tables,
     logits = L.unembed(params["embed"], cfg, x)
     page_mass = mass_sum / max(1, n_layers)
     page_mass = jnp.where(active[:, None], page_mass, 0.0)
-    return logits, new_kv, page_mass
+    return logits, new_kv, page_mass, moe_sum
 
 
 def _sample_row(logits_row, key, temperature):
@@ -1190,22 +1206,27 @@ def decode_macro_step(params, cfg: ModelConfig, kv, tables, gid_tables,
 
     Returns ``(tokens_out int32[n_steps, B] (-1 = row not alive), new_kv,
     state)`` with ``state = {mass_sum f32[B, n_row_pages], alive_steps
-    int32[B], pos, keys, iters, emitted, stopped bool[B]}`` -- everything
-    the scheduler needs to retire finished requests and feed the monitor
-    one merged mass per period.
+    int32[B], pos, keys, iters, emitted, stopped bool[B], counts
+    int32[B + 2]}`` -- everything the scheduler needs to retire finished
+    requests and feed the monitor one merged mass per period.  ``counts``
+    is ``alive_steps`` followed by the expert layers' counts summed over
+    steps and layers (held token-expert pairs, held experts touched), so
+    one download brings both.
     """
     b = tokens.shape[0]
     n_row_pages = tables.shape[1]
 
     def run(carry):
-        kv, tok, pos, ks, it, em, stopped, mass_sum, alive_steps = carry
+        (kv, tok, pos, ks, it, em, stopped, mass_sum, alive_steps,
+         moe_sum) = carry
         alive = (pos >= 0) & ~stopped
         cur = jnp.where(alive, pos, -1)
-        logits, kv, mass = _paged_decode_core(
+        logits, kv, mass, moe = _paged_decode_core(
             params, cfg, kv, tables, gid_tables, tok, cur,
             page_size=page_size, impl=impl, cond=cond,
             state_cols=state_cols, mesh=mesh, shard=shard)
         mass_sum = mass_sum + mass            # core zeroes dead rows
+        moe_sum = moe_sum + moe
         alive_steps = alive_steps + alive.astype(jnp.int32)
         ks2 = jax.vmap(jax.random.fold_in)(ks, it)
         new_tok = jax.vmap(_sample_row)(logits, ks2, temps)   # [B, 1]
@@ -1219,7 +1240,7 @@ def decode_macro_step(params, cfg: ModelConfig, kv, tables, gid_tables,
         pos = jnp.where(alive, pos + 1, pos)
         out = jnp.where(alive, tok[:, 0], -1)
         return (kv, tok, pos, ks, it, em, stopped, mass_sum,
-                alive_steps), out
+                alive_steps, moe_sum), out
 
     def body(carry, _):
         # all rows done: skip the model entirely (lax.cond executes one
@@ -1246,12 +1267,14 @@ def decode_macro_step(params, cfg: ModelConfig, kv, tables, gid_tables,
     init = (kv, tokens, cur_pos, keys, jnp.asarray(iters, jnp.int32),
             em0, stopped0,
             jnp.zeros((b, n_row_pages), jnp.float32),
-            jnp.zeros((b,), jnp.int32))
+            jnp.zeros((b,), jnp.int32), jnp.zeros((2,), jnp.int32))
     (kv, tok, pos, ks, it, em, stopped, mass_sum,
-     alive_steps), toks_out = jax.lax.scan(body, init, None, length=n_steps)
+     alive_steps, moe_sum), toks_out = jax.lax.scan(body, init, None,
+                                                    length=n_steps)
     state = {"mass_sum": mass_sum, "alive_steps": alive_steps, "pos": pos,
              "keys": ks, "iters": it, "emitted": em, "stopped": stopped,
-             "last_tok": tok}
+             "last_tok": tok,
+             "counts": jnp.concatenate([alive_steps, moe_sum])}
     return toks_out, kv, state
 
 
@@ -1264,8 +1287,6 @@ def init_specs_only(cfg: ModelConfig):
     """
     import dataclasses as _dc
 
-    from repro.models.config import MLAConfig as _MLA
-    from repro.models.config import MoEConfig as _MoE
 
     kv = 4 if cfg.num_heads == cfg.num_kv_heads else min(4, max(
         1, cfg.num_kv_heads))
@@ -1277,12 +1298,15 @@ def init_specs_only(cfg: ModelConfig):
         lru_width=32 if cfg.lru_width else 0,
         cond_dim=64 if cfg.cond_dim else 0,
         window_size=min(cfg.window_size, 8) if cfg.window_size else 0,
-        moe=(_MoE(num_experts=8, top_k=2, d_expert=16,
-                  num_shared=cfg.moe.num_shared,
-                  d_shared=16 if (cfg.moe and cfg.moe.d_shared) else 0)
+        moe=(_dc.replace(cfg.moe, num_experts=8, top_k=2, d_expert=16,
+                         d_shared=16 if cfg.moe.d_shared else 0,
+                         n_group=min(cfg.moe.n_group, 4),
+                         topk_group=min(cfg.moe.topk_group, 2),
+                         held_start=0, held=0)
              if cfg.moe else None),
-        mla=(_MLA(q_lora_rank=16, kv_lora_rank=8, qk_nope_dim=8,
-                  qk_rope_dim=4, v_head_dim=8) if cfg.mla else None),
+        mla=(_dc.replace(cfg.mla, q_lora_rank=16, kv_lora_rank=8,
+                         qk_nope_dim=8, qk_rope_dim=4, v_head_dim=8)
+             if cfg.mla else None),
         remat=False, moe_impl="dense",
     )
     return init(jax.random.PRNGKey(0), twin)[1]

@@ -1,20 +1,28 @@
 """Mixture-of-Experts layers.
 
-Two implementations sharing identical routing math (softmax over top-k
-logits, renormalised):
+Two implementations sharing the router (``route``: softmax over top-k
+logits, renormalised; or DeepSeek-V3's sigmoid group-limited rule, chosen
+by ``MoEConfig.scoring_func``):
 
-  * ``dense``     -- computes every expert for every token; the numerics
-                     oracle used by smoke/property tests (tiny configs only).
-  * ``shard_map`` -- production expert-parallel path: activations are
-                     replicated across the ``model`` mesh axis (TP), experts
-                     are sharded over it; each shard sort-dispatches tokens
-                     to its local experts under a capacity bound and the
-                     partial outputs are ``psum``-combined.  Communication
-                     profile == one TP all-reduce per MoE layer, no
-                     all-to-all -- the right trade on ICI-rich TPU meshes.
+  * ``grouped``   -- the served layer (and the default everywhere): told
+                     which contiguous block of experts it holds, it routes
+                     over all of them, sorts the token-expert pairs whose
+                     expert it holds by expert and runs one grouped matmul
+                     per projection over the held experts
+                     (``kernels.ops.expert_gmm``).  Dropless: the buffer
+                     holds the worst case, T x min(k, held) rows, chunked
+                     over tokens so it stays bounded.  Pairs whose expert
+                     is not held add nothing (another chip's share); on
+                     one chip there is no exchange.
+  * ``shard_map`` -- expert-parallel training path over a mesh:
+                     activations are replicated across the ``model`` mesh
+                     axis (TP), experts are sharded over it; each shard
+                     sort-dispatches tokens to its local experts under a
+                     capacity bound (it drops past it) and the partial
+                     outputs are ``psum``-combined.
 
-Both are fully differentiable (capacity drops use stop-gradient-free
-masking; indices are non-differentiable by construction).
+Both are fully differentiable (indices are non-differentiable by
+construction).
 """
 from __future__ import annotations
 
@@ -25,22 +33,31 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.config import ModelConfig
-from repro.models.layers import _dense_init, split_tree
+from repro.kernels import ops
+from repro.models.config import ModelConfig, MoEConfig
+from repro.models.layers import PIECES, _dense_init, mm, pieces, split_tree
 
 Params = Dict[str, Any]
+
+#: rows of the grouped layer's dispatch buffer per token chunk: a long
+#: prefill runs in chunks of DISPATCH_ROWS // (min(k, held) x PIECES)
+#: tokens (PIECES rows a pair under bfloat16 experts, else 1)
+DISPATCH_ROWS = 16384
 
 
 def moe_init(key, cfg: ModelConfig):
     mo = cfg.moe
     d, e, f = cfg.d_model, mo.num_experts, mo.d_expert
+    h = mo.n_held
     ks = jax.random.split(key, 5)
     tree = {
         "router": _dense_init(ks[0], (d, e), ("embed", None)),
-        "wi_gate": _dense_init(ks[1], (e, d, f), ("expert", "embed", "mlp")),
-        "wi_up": _dense_init(ks[2], (e, d, f), ("expert", "embed", "mlp")),
-        "wo": _dense_init(ks[3], (e, f, d), ("expert", "mlp", "embed")),
+        "wi_gate": _dense_init(ks[1], (h, d, f), ("expert", "embed", "mlp")),
+        "wi_up": _dense_init(ks[2], (h, d, f), ("expert", "embed", "mlp")),
+        "wo": _dense_init(ks[3], (h, f, d), ("expert", "mlp", "embed")),
     }
+    if mo.scoring_func == "sigmoid":
+        tree["router_bias"] = (jnp.zeros((e,), jnp.float32), (None,))
     if mo.num_shared:
         fs = (mo.d_shared or mo.d_expert) * mo.num_shared
         k5, k6, k7 = jax.random.split(ks[4], 3)
@@ -53,12 +70,49 @@ def moe_init(key, cfg: ModelConfig):
 
 
 def _route(x, router_w, top_k: int):
-    """Common routing: returns (weights [T,k], idx [T,k], probs [T,E])."""
+    """Softmax routing: returns (weights [T,k], idx [T,k], probs [T,E])."""
     logits = (x @ router_w.astype(x.dtype)).astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_i = jax.lax.top_k(probs, top_k)
     top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     return top_p.astype(x.dtype), top_i, probs
+
+
+def _route_sigmoid(x, router_w, bias, mo: MoEConfig):
+    """DeepSeek-V3's router (``scoring_func`` sigmoid, ``topk_method``
+    noaux_tc), in float32 as its gate computes it: hidden states and the
+    router weight are cast to float32 and multiplied at full precision --
+    bfloat16 logits would flip near-tie selections against the float32
+    published rule.  Selection ranks ``sigmoid(logits) + bias``: a group's
+    score is the sum of its 2 best, the best ``topk_group`` of ``n_group``
+    groups are kept and the rest excluded, and the top ``k`` of the kept
+    experts are chosen.  The weights are the chosen experts' *unbiased*
+    scores over their sum, times ``routed_scaling_factor``.  Returns
+    (weights f32 [T,k], idx [T,k], scores [T,E])."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    biased = scores + bias.astype(jnp.float32)
+    t, e = scores.shape
+    g = mo.n_group
+    group_score = jax.lax.top_k(biased.reshape(t, g, e // g), 2)[0].sum(-1)
+    _, keep = jax.lax.top_k(group_score, mo.topk_group)        # [T, kg]
+    kept = jnp.any(keep[:, :, None] == jnp.arange(g)[None, None], axis=1)
+    kept = jnp.repeat(kept, e // g, axis=1)                     # [T, E]
+    _, idx = jax.lax.top_k(jnp.where(kept, biased, -jnp.inf), mo.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * mo.routed_scaling_factor, idx, scores
+
+
+def route(p: Params, mo: MoEConfig, x):
+    """The configured router over ``x`` [T, d]: (weights [T,k], expert ids
+    [T,k] over all ``num_experts``, probs/scores [T,E])."""
+    if mo.scoring_func == "sigmoid":
+        return _route_sigmoid(x, p["router"], p["router_bias"], mo)
+    if mo.scoring_func != "softmax":
+        raise ValueError(f"unknown scoring_func {mo.scoring_func!r}")
+    return _route(x, p["router"], mo.top_k)
 
 
 def _aux_loss(probs, top_i, num_experts: int):
@@ -69,31 +123,101 @@ def _aux_loss(probs, top_i, num_experts: int):
 
 
 def _shared_out(p, x):
-    h = jax.nn.silu(x @ p["wi_gate"].astype(x.dtype)) * (
-        x @ p["wi_up"].astype(x.dtype))
-    return h @ p["wo"].astype(x.dtype)
+    h = jax.nn.silu(mm(x, p["wi_gate"])) * mm(x, p["wi_up"])
+    return mm(h, p["wo"])
 
 
 # ---------------------------------------------------------------------------
-# dense oracle
+# grouped dropless layer over the held experts
 # ---------------------------------------------------------------------------
 
 
-def moe_apply_dense(p: Params, cfg: ModelConfig, x) -> Tuple[Any, Any]:
-    """x: [B,S,d] -> (y, aux_loss).  Computes all experts (oracle)."""
+def _pieces_of(w, x) -> int:
+    """Rows a pair takes in the grouped matmul: ``PIECES`` where bfloat16
+    experts meet float32 activations (``layers.mm``'s exact products),
+    else 1."""
+    return PIECES if w.dtype.itemsize < x.dtype.itemsize else 1
+
+
+def _gmm(x, w, sizes):
+    """The grouped matmul of rows ``x`` [R, d] sorted by group, float32
+    out.  Under bfloat16 experts each row runs as its bfloat16 pieces on
+    adjacent rows of its group (the weights read once), summed after."""
+    n = _pieces_of(w, x)
+    if n == 1:
+        return ops.expert_gmm(x.astype(w.dtype), w, sizes)
+    r = x.shape[0]
+    xs = jnp.stack(pieces(x, w.dtype, n), axis=1).reshape(r * n, -1)
+    return ops.expert_gmm(xs, w, sizes * n).reshape(r, n, -1).sum(axis=1)
+
+
+def _dispatch_chunk(p: Params, mo: MoEConfig, xc, wc, ic, vc):
+    """One token chunk through the held experts.  xc [C,d]; wc/ic [C,k]
+    routing weights and global expert ids; vc [C] tokens that count.
+    Returns (y [C,d] f32, int32[2]: held pairs, held experts touched)."""
+    c, k = ic.shape
+    held = mo.n_held
+    rows = c * min(k, held)            # the worst case: nothing is dropped
+    le = ic - mo.held_start
+    mine = (le >= 0) & (le < held) & vc[:, None]                # [C, k]
+    key = jnp.where(mine, le, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)          # held pairs first
+    sizes = jnp.zeros((held,), jnp.int32).at[key].add(1, mode="drop")
+    xs = xc[order[:rows] // k]
+    g = _gmm(xs, p["wi_gate"], sizes)
+    u = _gmm(xs, p["wi_up"], sizes)
+    out = _gmm(jax.nn.silu(g) * u, p["wo"], sizes)
+    # each held pair reads its row back (a gather, not a scatter); rows
+    # past the held pairs hold no pair (the kernel leaves them unwritten):
+    # select, never multiply, them away
+    slot = jnp.zeros((c * k,), jnp.int32).at[order].set(
+        jnp.arange(c * k, dtype=jnp.int32))
+    rows_of = out[jnp.minimum(slot, rows - 1)].reshape(c, k, -1)
+    y = jnp.sum(jnp.where(mine[..., None],
+                          rows_of * wc[..., None].astype(out.dtype), 0.0),
+                axis=1)
+    counts = jnp.stack([mine.sum(dtype=jnp.int32),
+                        (sizes > 0).sum(dtype=jnp.int32)])
+    return y, counts
+
+
+def moe_apply_grouped(p: Params, cfg: ModelConfig, x, valid=None
+                      ) -> Tuple[Any, Any, Any]:
+    """x: [B,S,d] -> (y, aux_loss, counts int32[2]).  ``valid`` [B,S]
+    marks the tokens that count (padding and idle rows route nowhere; they
+    get the shared experts only).  ``counts``: token-expert pairs routed to
+    held experts, and held experts with at least one pair, summed over
+    token chunks."""
     mo = cfg.moe
     b, s, d = x.shape
-    xt = x.reshape(b * s, d)
-    w, idx, probs = _route(xt, p["router"], mo.top_k)
-    h = jnp.einsum("td,edf->tef", xt, p["wi_gate"].astype(x.dtype))
-    u = jnp.einsum("td,edf->tef", xt, p["wi_up"].astype(x.dtype))
-    y_all = jnp.einsum("tef,efd->ted", jax.nn.silu(h) * u,
-                       p["wo"].astype(x.dtype))        # [T,E,d]
-    sel = jnp.take_along_axis(y_all, idx[:, :, None], axis=1)  # [T,k,d]
-    y = jnp.sum(sel * w[:, :, None], axis=1)
+    t = b * s
+    xt = x.reshape(t, d)
+    w, idx, probs = route(p, mo, xt)
+    vt = (jnp.ones((t,), bool) if valid is None
+          else jnp.asarray(valid, bool).reshape(t))
+    chunk = max(1, DISPATCH_ROWS // (min(mo.top_k, mo.n_held)
+                                     * _pieces_of(p["wi_gate"], xt)))
+    if t <= chunk:
+        y, counts = _dispatch_chunk(p, mo, xt, w, idx, vt)
+    else:
+        n = -(-t // chunk)
+        pad = n * chunk - t
+
+        def split(a):
+            a = jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+            return a.reshape((n, chunk) + a.shape[1:])
+
+        y, counts = jax.lax.map(
+            lambda a: _dispatch_chunk(p, mo, *a),
+            (split(xt), split(w), split(idx), split(vt)))
+        y = y.reshape(n * chunk, d)[:t]
+        counts = counts.sum(axis=0)
+    y = y.astype(x.dtype)
     if mo.num_shared:
-        y = y + _shared_out(p["shared"], xt)
-    return y.reshape(b, s, d), _aux_loss(probs, idx, mo.num_experts)
+        y = y + _shared_out(p["shared"], xt).astype(x.dtype)
+    aux = (jnp.float32(0.0) if mo.scoring_func == "sigmoid"
+           else _aux_loss(probs, idx, mo.num_experts))
+    return y.reshape(b, s, d), aux, counts
 
 
 # ---------------------------------------------------------------------------
@@ -188,7 +312,11 @@ def moe_apply_shard_map(p: Params, cfg: ModelConfig, x, mesh,
     return y.reshape(b, s, d), aux
 
 
-def moe_apply(p: Params, cfg: ModelConfig, x, mesh=None):
+def moe_apply(p: Params, cfg: ModelConfig, x, mesh=None, valid=None):
+    """(y, aux_loss, counts int32[2]): the expert-parallel training path
+    over a mesh, else the grouped layer (counts of the shard_map path are
+    not kept: zeros)."""
     if cfg.moe_impl == "shard_map" and mesh is not None:
-        return moe_apply_shard_map(p, cfg, x, mesh)
-    return moe_apply_dense(p, cfg, x)
+        y, aux = moe_apply_shard_map(p, cfg, x, mesh)
+        return y, aux, jnp.zeros((2,), jnp.int32)
+    return moe_apply_grouped(p, cfg, x, valid)

@@ -99,13 +99,15 @@ _ALL = [
     # -- serve: the continuous-batching scheduler (wall clock) ---------------
     _ev("serve.admit",
         ("step", "joiners", "pages", "queue_depth", "rids", "wait_ms",
-         "stall_ms"),
+         "stall_ms", "moe_pairs_held", "moe_experts_touched"),
         "one admission batch: requests packed-prefilled together, pages "
         "allocated, queue depth after, the joiners' rids and each one's "
         "queue wait (submit to the start of its admission); the "
         "pipelined loop adds stall_ms, the batch's worst "
         "reservation-to-activation admission stall (the SLO the chunk "
-        "knob trades against)"),
+        "knob trades against); a model with expert layers adds the "
+        "packed prefill's token-expert pairs routed to held experts and "
+        "held experts touched, summed over its expert layers"),
     _ev("serve.retire",
         ("step", "rid", "tokens", "status", "deadline_ms"),
         "a request left the system with a typed terminal status -- "
@@ -130,12 +132,17 @@ _ALL = [
         "restarts are exhausted and the loop stays synchronous)"),
     _ev("serve.macro",
         ("step", "n_steps", "tokens", "active", "fetched", "wall_ms",
-         "straggler", "kv_pages_live", "kv_pages_table"),
+         "straggler", "kv_pages_live", "kv_pages_table", "moe_pairs_held",
+         "moe_experts_touched"),
         "one macro-step launch: a movement period of device-resident "
         "decode -- scan length, tokens served, mean active rows, up-front "
         "prefetch misses, wall time, StepTimer straggler flag, the active "
         "rows' live KV pages at launch and the page table's size (rows x "
-        "table pages): the paged kernel walks only the live ones"),
+        "table pages): the paged kernel walks only the live ones; the "
+        "active rows' token-expert pairs routed to held experts and the "
+        "held experts with at least one pair, each summed over the scan's "
+        "steps and expert layers (0 without expert layers; counted on the "
+        "device, downloaded with the alive-step counts)"),
     _ev("serve.stream",
         ("phase", "tokens", "wall_ms"),
         "single-stream monitored_generate started/finished"),

@@ -524,6 +524,8 @@ class ContinuousBatcher:
 
         self.tok = jnp.zeros((max_active, 1), jnp.int32)
         self.pos = jnp.zeros((max_active,), jnp.int32)
+        #: the last packed prefill's expert counts (device int32[2])
+        self._prefill_moe = None
         self.rows_free = list(range(max_active - 1, -1, -1))
         self.active: Dict[int, Request] = {}
         self.queue: "collections.deque[Request]" = collections.deque()
@@ -761,11 +763,19 @@ class ContinuousBatcher:
         with _obs.RECORDER.span("serve.prefill", joiners=len(batch)):
             emitted = self._prefill(batch)
         if (r := _obs.RECORDER).enabled:
+            moe = {}
+            if self._prefill_moe is not None:
+                # the packed prefill's expert counts; its logits were
+                # downloaded above, so the pass is done
+                pairs, touched = np.asarray(self._prefill_moe)
+                moe = dict(moe_pairs_held=int(pairs),
+                           moe_experts_touched=int(touched))
             r.emit("serve.admit", step=self.step_idx, joiners=len(batch),
                    pages=int(sum(b.n_alloc for b in batch)),
                    queue_depth=len(self.queue),
                    rids=[b.rid for b in batch],
-                   wait_ms=[(t_admit - b._t_submit) * 1e3 for b in batch])
+                   wait_ms=[(t_admit - b._t_submit) * 1e3 for b in batch],
+                   **moe)
             r.count("serve.admitted", len(batch))
             r.gauge("serve.queue_depth", len(self.queue))
         return emitted
@@ -897,6 +907,7 @@ class ContinuousBatcher:
                 [r.prompt for r in batch])
         else:               # recurrent state: one request at a time
             logits_b, cache_b = None, None
+        self._prefill_moe = None if cache_b is None else cache_b.get("moe")
 
         if self.paged and self._batched_prefill:
             # one on-device gather/scatter writes EVERY joiner's KV for
@@ -1402,7 +1413,9 @@ class ContinuousBatcher:
         with _obs.RECORDER.span("serve.macro.wait"):
             toks_np = np.asarray(fl["toks"])
             mass_sum = np.asarray(st["mass_sum"])
-            alive_steps = np.asarray(st["alive_steps"])
+            # alive steps per row, then the expert counts: one download
+            counts = np.asarray(st["counts"])
+            alive_steps = counts[:self.max_active]
             stopped = np.asarray(st["stopped"])
             iters_out = np.asarray(st["iters"])
         # the downloads above force the device sync: the stop covers the
@@ -1485,7 +1498,9 @@ class ContinuousBatcher:
                    tokens=len(emitted), active=n_active,
                    fetched=int(fl["fetched"]), wall_ms=macro_wall * 1e3,
                    straggler=straggler, kv_pages_live=live,
-                   kv_pages_table=self.max_active * self.n_row_pages)
+                   kv_pages_table=self.max_active * self.n_row_pages,
+                   moe_pairs_held=int(counts[self.max_active]),
+                   moe_experts_touched=int(counts[self.max_active + 1]))
             r.count("serve.tokens", len(emitted))
         return emitted, payload
 

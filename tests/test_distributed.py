@@ -71,7 +71,7 @@ def test_moe_shard_map_matches_dense_oracle():
         p, _ = M.moe_init(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model),
                               jnp.float32)
-        y_dense, aux_d = M.moe_apply_dense(p, cfg, x)
+        y_dense, aux_d, _ = M.moe_apply_grouped(p, cfg, x)
         with jax.set_mesh(mesh):
             y_sm, aux_s = M.moe_apply_shard_map(p, cfg, x, mesh)
         np.testing.assert_allclose(np.asarray(y_dense), np.asarray(y_sm),

@@ -106,6 +106,23 @@ def test_paged_attention_mla_compiles_at_deepseek_v3_width(one_chip, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows,sizes", [(64, (1, 1, 0, 0, 0, 0, 0, 0)),
+                                        (16384, (64,) * 8)])
+def test_expert_gmm_compiles_at_deepseek_v3_width(one_chip, rows, sizes):
+    """The expert layer's grouped matmul at DeepSeek-V3's widths over 8
+    held experts, at a decode step's buffer (8 rows x top 8) and a prefill
+    chunk's: gate/up (7168 -> 2048) and down (2048 -> 7168)."""
+    cfg = C.get("deepseek-v3-671b")
+    d, f = cfg.d_model, cfg.moe.d_expert
+    for k, n in ((d, f), (f, d)):
+        fn = jax.jit(lambda x, w, g: ops.expert_gmm(x, w, g, impl="pallas"))
+        compiled = fn.lower(_sds((rows, k), jnp.bfloat16, one_chip),
+                            _sds((len(sizes), k, n), jnp.bfloat16, one_chip),
+                            _sds((len(sizes),), jnp.int32,
+                                 one_chip)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_served_macro_program_fits_one_v5e(one_chip):
     """The macro decode program ``chip_smoke.py`` serves -- qwen3-14b at
     published widths cut to 2 layers, 4 rows of 2112 positions, page 16 --
